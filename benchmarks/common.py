@@ -4,6 +4,12 @@ from __future__ import annotations
 
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
+# Benchmarks are chip entry points: every compile goes to the one
+# persistent cache, set before the first compile.
+enable_compile_cache()
+
 
 class Csv:
     """Collects ``name,us_per_call,derived`` rows (one per measurement)."""

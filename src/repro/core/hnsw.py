@@ -90,6 +90,7 @@ import pickle
 import numpy as np
 
 from .quantize import QuantMeta, quantize_linear, quantize_linear_batch
+from ..kernels import ops
 from ..obs.metrics import default_registry
 
 __all__ = ["HNSWIndex", "quantized_l2_batch", "KERNEL_DISPATCH_MIN_ELEMS"]
@@ -126,15 +127,11 @@ KERNEL_DISPATCH_MIN_ELEMS = 4 << 20
 def _offload_distances(queries, codes, scales, zps, mids):
     """Offer one (B, D)-vs-(N, D) distance block to the TPU kernel.
 
-    Returns the (B, N) distances, or ``None`` when the kernel path is
-    unavailable (no jax, no TPU backend, block too small) — callers fall
-    back to the numpy decomposed form. Kept as a module-level hook so
-    tests can stub it to verify the seam is consulted.
+    Returns the (B, N) distances, or ``None`` when the seam declines (no
+    TPU backend, block too small) — callers fall back to the numpy
+    decomposed form. Kept as a module-level hook so tests can stub it to
+    verify the seam is consulted.
     """
-    try:
-        from repro.kernels import ops
-    except Exception:  # jax missing/broken: numpy fallback is fully featured
-        return None
     # This module's constant is the single size gate for the index path —
     # forwarded so ops' own default cannot silently re-gate behind it.
     return ops.quantized_l2_auto(queries, codes, scales, zps, mids,

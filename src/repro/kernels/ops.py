@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.metrics import default_registry
 from .dequant_matmul import dequant_matmul_int4_pallas, dequant_matmul_pallas
 from .flash_attention import flash_attention_pallas
 from .quantized_l2 import quantized_l2_pallas
@@ -23,9 +24,31 @@ __all__ = ["dequant_matmul", "dequant_matmul_auto", "dequant_matmul_int4",
 # the launch + host<->device transfer would swamp the distance math.
 KERNEL_DISPATCH_MIN_ELEMS = 4 << 20
 
+# Kernel launches per dispatch seam (docs/observability.md). Route "tpu" is
+# the Pallas kernel compiled for the TPU backend, "interpret" the same
+# kernel in interpret mode, "host" the numpy form the seam fell back to.
+_M_KERNEL_CALLS = default_registry().counter(
+    "neurstore_kernel_calls_total",
+    "Dispatch-seam kernel launches by kernel and route "
+    "(tpu / interpret / host).",
+    ("kernel", "route"),
+)
+
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _kernel_route() -> str:
+    return "tpu" if _on_tpu() else "interpret"
+
+
+def _row_block(n: int, block: int) -> int:
+    """Row block for ``n`` rows: ``block``, or ``n`` rounded up to the
+    8-row sublane multiple when fewer rows exist. Padding a few index
+    vertices to a full 128-row block would multiply a (n, 189M) code
+    block 64x in HBM."""
+    return block if n >= block else -(-n // 8) * 8
 
 
 def quantized_l2_auto(queries, codes, scales, zps, mids, *,
@@ -44,10 +67,10 @@ def quantized_l2_auto(queries, codes, scales, zps, mids, *,
     use this for CPU interpret-mode parity); ``force="numpy"`` always
     declines.
     """
-    if force == "numpy":
-        return None
     codes = np.asarray(codes)
-    if force != "kernel" and (not _on_tpu() or codes.size < min_elems):
+    if force == "numpy" or (
+            force != "kernel" and (not _on_tpu() or codes.size < min_elems)):
+        _M_KERNEL_CALLS.labels("quantized_l2", "host").inc()
         return None
     q2 = np.atleast_2d(np.asarray(queries, dtype=np.float32))
     n, d = codes.shape
@@ -57,11 +80,13 @@ def quantized_l2_auto(queries, codes, scales, zps, mids, *,
     # loop: once padded, the _pad_to calls inside quantized_l2 are no-ops
     # and each iteration is just one (jit-cached) kernel launch. d_true
     # carries the real dimension past the padding.
+    bn = _row_block(n, 128)
     bd = min(512, max(128, d)) if d < 512 else 512
-    codes_j = _pad_to(_pad_to(jnp.asarray(codes), 128, 0), bd, 1)
-    s = _pad_to(jnp.asarray(np.asarray(scales, dtype=np.float32)), 128, 0)
-    z = _pad_to(jnp.asarray(np.asarray(zps, dtype=np.float32)), 128, 0)
-    m = _pad_to(jnp.asarray(np.asarray(mids, dtype=np.float32)), 128, 0)
+    codes_j = _pad_to(_pad_to(jnp.asarray(codes), bn, 0), bd, 1)
+    s = _pad_to(jnp.asarray(np.asarray(scales, dtype=np.float32)), bn, 0)
+    z = _pad_to(jnp.asarray(np.asarray(zps, dtype=np.float32)), bn, 0)
+    m = _pad_to(jnp.asarray(np.asarray(mids, dtype=np.float32)), bn, 0)
+    _M_KERNEL_CALLS.labels("quantized_l2", _kernel_route()).inc(len(q2))
     out = [
         np.asarray(
             quantized_l2(_pad_to(jnp.asarray(q), bd, 0), codes_j, s, z, m,
@@ -165,12 +190,14 @@ def dequant_matmul_auto(x, base, base_scale, base_zp, delta, delta_scale,
     base = np.asarray(base)
     use_kernel = force == "kernel" or (
         force is None and _on_tpu() and base.size >= min_elems)
+    fn = dequant_matmul_int4 if packed else dequant_matmul
     if use_kernel:
+        _M_KERNEL_CALLS.labels(fn.__name__, _kernel_route()).inc()
         xj = jnp.asarray(np.asarray(x, dtype=np.float32))
-        fn = dequant_matmul_int4 if packed else dequant_matmul
         y = fn(xj, jnp.asarray(base), float(base_scale), float(base_zp),
                jnp.asarray(delta), float(delta_scale), float(delta_zp))
         return np.asarray(y, dtype=np.float32)
+    _M_KERNEL_CALLS.labels(fn.__name__, "host").inc()
     ops = scratch.get("cpu") if scratch is not None else None
     if ops is None:
         bf = base.astype(np.float32) * np.float32(base_scale)
@@ -214,16 +241,17 @@ def quantized_l2(query, codes, scales, zps, mids,
     if interpret is None:
         interpret = not _on_tpu()
     n, d = codes.shape
+    bn = _row_block(n, block_n)
     bd = min(block_d, max(128, d)) if d < block_d else block_d
     qp = _pad_to(jnp.asarray(query), bd, 0)
-    codesp = _pad_to(_pad_to(jnp.asarray(codes), block_n, 0), bd, 1)
+    codesp = _pad_to(_pad_to(jnp.asarray(codes), bn, 0), bd, 1)
     # Padded rows: scale=0, mid=0 → dequantize to 0; padded query dims are 0,
     # so padded D contributes 0 and padded rows are sliced off below.
-    scalesp = _pad_to(jnp.asarray(scales), block_n, 0)
-    zpsp = _pad_to(jnp.asarray(zps), block_n, 0)
-    midsp = _pad_to(jnp.asarray(mids), block_n, 0)
+    scalesp = _pad_to(jnp.asarray(scales), bn, 0)
+    zpsp = _pad_to(jnp.asarray(zps), bn, 0)
+    midsp = _pad_to(jnp.asarray(mids), bn, 0)
     out = quantized_l2_pallas(qp, codesp, scalesp, zpsp, midsp,
-                              block_n=block_n, block_d=bd,
+                              block_n=bn, block_d=bd,
                               d_true=d if d_true is None else d_true,
                               interpret=interpret)
     return out[:n]

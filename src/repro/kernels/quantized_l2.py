@@ -58,7 +58,9 @@ def _ql2_kernel(q_ref, codes_ref, scal_ref, qs_ref, o_ref,
         sum_ref[...] = jnp.zeros_like(sum_ref)
         sq_ref[...] = jnp.zeros_like(sq_ref)
 
-    c = codes_ref[...].astype(jnp.float32)       # (bn, bd)
+    # (bn, bd) uint8 index codes; via int32 because Mosaic has no
+    # uint8 -> float32 cast.
+    c = codes_ref[...].astype(jnp.int32).astype(jnp.float32)
     q = q_ref[...].astype(jnp.float32)           # (1, bd) broadcasts over rows
     dot_ref[...] += jnp.sum(c * q, axis=-1, keepdims=True)
     sum_ref[...] += jnp.sum(c, axis=-1, keepdims=True)
@@ -109,8 +111,10 @@ def quantized_l2_pallas(
     )  # (N, 3)
     qf = query.astype(jnp.float32)
     # Query statistics for the decomposed form; zero padding leaves both
-    # unchanged, so computing them on the padded query is exact.
-    qs = jnp.stack([jnp.vdot(qf, qf), jnp.sum(qf)]).reshape(1, 2)
+    # unchanged, so computing them on the padded query is exact. A
+    # multiply-and-sum, not vdot: XLA's default-precision dot on the TPU
+    # rounds f32 operands to bf16.
+    qs = jnp.stack([jnp.sum(qf * qf), jnp.sum(qf)]).reshape(1, 2)
     grid = (n // block_n, n_d)
     out = pl.pallas_call(
         functools.partial(_ql2_kernel, n_d=n_d, d_true=d_true),

@@ -16,21 +16,26 @@ HBM_BW = 819e9                 # bytes/s per chip
 ICI_BW = 50e9                  # bytes/s per link
 
 
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """Mesh with Auto axes: the one place meshes are built.
+
+    ``jax.make_mesh`` defaults to Explicit axes, on which
+    ``with_sharding_constraint`` (``ShardingCtx.constrain``) refuses to
+    run; the GSPMD rule tables in ``repro.distributed.sharding`` need Auto.
+    """
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (tests, small-scale pipelines)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Whatever devices exist on this host (smoke tests, examples)."""
     n = len(jax.devices())
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return make_mesh((n // model_parallel, model_parallel), ("data", "model"))

@@ -4,10 +4,10 @@ gradient compression."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.optim import adamw_init
 
@@ -88,7 +88,7 @@ def test_elastic_restore_different_mesh(tmp_path):
     mgr.save(3, params)
     _, state = mgr.restore()
     # Re-shard onto this host's devices (1 device ↔ N devices both fine).
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     from repro.distributed import sharding as sh
     from repro.launch import shardings as shd
 
@@ -134,18 +134,13 @@ def test_gradient_compression_error_feedback():
     np.testing.assert_allclose(acc / n, true_g, atol=2e-5)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="cross_pod_sync needs the top-level jax.shard_map API (jax>=0.6); "
-    "this environment's jax predates it",
-)
 def test_cross_pod_sync():
     from repro.distributed.compression import cross_pod_sync, init_error_state
 
     if len(jax.devices()) < 2:
-        mesh = jax.make_mesh((1,), ("pod",))
+        mesh = make_mesh((1,), ("pod",))
     else:
-        mesh = jax.make_mesh((2,), ("pod",))
+        mesh = make_mesh((2,), ("pod",))
     p = mesh.devices.size
     rng = np.random.default_rng(1)
     per_pod = jnp.asarray(rng.normal(0, 1e-3, (p, 32, 16)).astype(np.float32))

@@ -11,6 +11,7 @@ from repro.configs import get_config
 from repro.distributed import sharding as sh
 from repro.launch import shardings as shd
 from repro.launch.hlo_stats import collective_stats
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_serve_step, make_train_step
 from repro.models import init_cache, init_params
 from repro.optim import adamw_init
@@ -19,7 +20,7 @@ KEY = jax.random.PRNGKey(0)
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_param_specs_cover_every_leaf():
@@ -81,7 +82,7 @@ def test_sharded_serve_step_runs():
 
 def test_fit_spec_divisibility():
     """fit_spec drops/replaces axes whose size doesn't divide the dim."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # These mesh axes are size 1 → everything divides; test the logic
     # directly with a fake 16×16 shape table instead.
     from repro.launch.shardings import _fits

@@ -241,3 +241,32 @@ def test_dequant_matmul_auto_rejects_bad_force():
         ops.dequant_matmul_auto(
             np.zeros((1, 2), np.float32), np.zeros((2, 2), np.int8),
             1.0, 0.0, np.zeros((2, 2), np.int8), 1.0, 0.0, force="tpu")
+
+
+def test_dispatch_seams_count_launches_by_route():
+    """neurstore_kernel_calls_total: the interpret-mode kernel counts as
+    route=interpret off the TPU, the numpy form as route=host, and
+    quantized_l2 counts one launch per query row."""
+    from repro.obs.metrics import default_registry
+
+    def count(kernel, route):
+        return default_registry().sample_value(
+            "neurstore_kernel_calls_total",
+            {"kernel": kernel, "route": route}) or 0
+
+    k, n = 64, 128
+    base = RNG.integers(-128, 128, (k, n)).astype(np.int8)
+    x = RNG.normal(0, 1, (2, k)).astype(np.float32)
+    args = (x, base, 0.01, 0.0, base, 1e-4, 0.0)
+    before = {r: count("dequant_matmul", r) for r in ("interpret", "host")}
+    ops.dequant_matmul_auto(*args, force="kernel")
+    ops.dequant_matmul_auto(*args)  # small block, CPU: declined
+    assert count("dequant_matmul", "interpret") == before["interpret"] + 1
+    assert count("dequant_matmul", "host") == before["host"] + 1
+
+    codes = RNG.integers(0, 256, (5, 256)).astype(np.uint8)
+    quant = (np.full(5, 0.01), np.zeros(5), np.zeros(5))
+    before = count("quantized_l2", "interpret")
+    ops.quantized_l2_auto(RNG.normal(0, 1, (3, 256)), codes, *quant,
+                          force="kernel")
+    assert count("quantized_l2", "interpret") == before + 3
