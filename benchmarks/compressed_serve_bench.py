@@ -24,7 +24,9 @@ session pair to report the int4-packed bytes-per-weight (1.5 vs 2.0)
 and check compressed/materialized token parity at that precision.
 
 Gates (``benchmarks/perf_gate.py``): per phase, ``bytes_ratio``
-(compressed ÷ materialized weight-operand traffic) strictly < 1.0, and
+(compressed ÷ materialized operand traffic: the ``operand_bytes`` of the
+compressed session's seam spans over the materialized provider's own
+tally of float32 weights and gathered rows) strictly < 1.0, and
 ``throughput_ratio`` (compressed ÷ materialized session tokens/s) ≥ 0.8
 — on CPU the decomposed gemm folds to a single combined-operand gemm in
 steady state, and the compressed session skips the up-front float64
@@ -53,6 +55,7 @@ from repro.launch.compressed_serve import (
     greedy_decode,
     save_decoder,
 )
+from repro.obs.trace import recent_traces
 
 # Bumped whenever the JSON layout changes (parsed by benchmarks/perf_gate.py).
 SCHEMA_VERSION = 2
@@ -62,6 +65,15 @@ SMOKE_SPEC = DecoderSpec(d_model=128, n_heads=4, n_kv_heads=2, d_ff=256,
 FULL_SPEC = DecoderSpec(d_model=512, n_heads=8, n_kv_heads=4, d_ff=1024,
                         n_layers=4, vocab_size=2048)
 PROMPT = ((1, 7, 42),)
+
+
+def _seam_tally() -> dict:
+    """Matmul calls and operand bytes of the newest ``generate`` request,
+    read off its ``dequant_matmul*`` seam spans."""
+    gen = next(r for r in reversed(recent_traces()) if r.name == "generate")
+    seam = [s for s in gen.walk() if s.name.startswith("dequant_matmul")]
+    return {"matmul_calls": len(seam),
+            "bytes_moved": sum(s.attrs["operand_bytes"] for s in seam)}
 
 
 def _session(root: str, spec: DecoderSpec, kind: str, steps: int,
@@ -77,7 +89,8 @@ def _session(root: str, spec: DecoderSpec, kind: str, steps: int,
         setup_s = time.perf_counter() - t0
         tokens = greedy_decode(provider, spec, prompt, steps)
         total_s = time.perf_counter() - t0
-        counters = dict(provider.counters)
+        counters = (_seam_tally() if kind == "compressed"
+                    else dict(provider.counters))
         provider.close()
     finally:
         engine.close()

@@ -21,9 +21,9 @@ Operand-normalization details the kernels don't know about live here:
   nibble codes per byte (``kernels.ops.pack_int4`` layout) — 1.5 HBM
   bytes per weight element on TPU instead of 2.0.
 
-Bytes-moved accounting (``counters``) charges each matmul its *weight
-operand* traffic — the quantity the compressed path exists to shrink —
-plus per-row traffic for embedding gathers.
+Each matmul is a span of the seam (``dequant_matmul`` or
+``dequant_matmul_int4``, see ``kernels.ops.dequant_matmul_auto``) that
+carries its route, logical shape and operand bytes.
 """
 
 from __future__ import annotations
@@ -104,8 +104,6 @@ class CompressedModel:
         self.force = force
         self._weights: dict[str, CompressedTensor] = {}
         self._vectors: dict[str, np.ndarray] = {}
-        self.counters = {"matmul_calls": 0, "gather_calls": 0,
-                         "bytes_moved": 0, "fused_elems": 0}
         #: Names whose bytes were served through the kernel seam — the
         #: zero-materialize acceptance test asserts ``materialize()`` /
         #: ``tensor()`` are never called for these.
@@ -123,15 +121,10 @@ class CompressedModel:
     def matmul(self, x: np.ndarray, name: str) -> np.ndarray:
         """``x @ dq(weight)`` on compressed operands; (M, K) → (M, N)."""
         w = self.weight(name)
-        y = dequant_matmul_auto(
+        return dequant_matmul_auto(
             x, w.base, w.base_scale, w.base_zp, w.delta, w.delta_scale,
             w.delta_zp, packed=w.packed, min_elems=self.min_elems,
             force=self.force, scratch=w.scratch)
-        c = self.counters
-        c["matmul_calls"] += 1
-        c["bytes_moved"] += w.operand_nbytes
-        c["fused_elems"] += w.k * w.n
-        return y
 
     def bytes_per_weight(self, name: str) -> float:
         """Kernel-operand bytes per weight element (2.0 int8, 1.5 int4)."""
@@ -158,9 +151,6 @@ class CompressedModel:
             delta = ((q.astype(np.float32) - entry["delta_zp"] + 0.5)
                      * entry["delta_scale"])
         self.kernel_served.add(name)
-        c = self.counters
-        c["gather_calls"] += 1
-        c["bytes_moved"] += codes.nbytes + int(q.size * nbit / 8)
         return (base + delta).astype(np.float32)
 
     def vector(self, name: str) -> np.ndarray:
@@ -171,9 +161,5 @@ class CompressedModel:
         return v
 
     # ------------------------------------------------------------ lifecycle
-    def reset_counters(self) -> None:
-        for key in self.counters:
-            self.counters[key] = 0
-
     def close(self) -> None:
         self.lm.close()

@@ -963,28 +963,29 @@ class StorageEngine:
                         best_dist[j], best_vid[j] = hit[0]
         deq_cache: dict[int, np.ndarray] = {}
         cand_pos: list[int] = []
-        for j in range(g):
-            vid = int(best_vid[j])
-            dist = (
-                float(best_dist[j])
-                if vid >= 0 and np.isfinite(best_dist[j]) else None
-            )
-            if vid >= 0:
-                base = deq_cache.get(vid)
-                if base is None:
-                    base = deq_cache[vid] = index.dequantize_vertex(vid)
-                delta = flats[j] - base
-                rng = float(delta.max() - delta.min())
-                # SHOULDCOMPRESS: delta range vs tau (§4.2).
-                if rng <= tau_:
-                    bases[j] = (vid, delta)
-                    explains[j] = {
-                        "vertex_id": vid, "outcome": "delta",
-                        "probe_distance": dist, "delta_range": rng,
-                    }
-                    continue
-            cand_pos.append(j)
-            explains[j] = {"probe_distance": dist}  # completed below
+        with trace("delta", g=g):
+            for j in range(g):
+                vid = int(best_vid[j])
+                dist = (
+                    float(best_dist[j])
+                    if vid >= 0 and np.isfinite(best_dist[j]) else None
+                )
+                if vid >= 0:
+                    base = deq_cache.get(vid)
+                    if base is None:
+                        base = deq_cache[vid] = index.dequantize_vertex(vid)
+                    delta = flats[j] - base
+                    rng = float(delta.max() - delta.min())
+                    # SHOULDCOMPRESS: delta range vs tau (§4.2).
+                    if rng <= tau_:
+                        bases[j] = (vid, delta)
+                        explains[j] = {
+                            "vertex_id": vid, "outcome": "delta",
+                            "probe_distance": dist, "delta_range": rng,
+                        }
+                        continue
+                cand_pos.append(j)
+                explains[j] = {"probe_distance": dist}  # completed below
         if not cand_pos:
             return bases, [], explains
         cand = flats[cand_pos]
@@ -1173,7 +1174,8 @@ class StorageEngine:
                         meta=meta,
                         qdelta=qd,
                     )
-                    rec.payload = encode_payload(rec)
+                    with trace("encode"):
+                        rec.payload = encode_payload(rec)
                     records.append(rec)
                     ex = probe_ex[i]
                     explain.append({
@@ -1414,7 +1416,8 @@ class StorageEngine:
                             meta=meta,
                             qdelta=qd,
                         )
-                        rec.payload = encode_payload(rec)
+                        with trace("encode"):
+                            rec.payload = encode_payload(rec)
                         records.append(rec)
                         ex = probe_ex[mi][i]
                         explain.append({
@@ -1968,7 +1971,7 @@ class StorageEngine:
 
         self._drain_released()
         for _attempt in range(64):
-            with trace("probe"), self._lock:
+            with trace("catalog"), self._lock:
                 entry = self.catalog.get(name)
                 if entry is None or entry.status != STATUS_COMMITTED:
                     if entry is not None and entry.status == STATUS_CORRUPT:

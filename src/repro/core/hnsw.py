@@ -92,6 +92,7 @@ import numpy as np
 from .quantize import QuantMeta, quantize_linear, quantize_linear_batch
 from ..kernels import ops
 from ..obs.metrics import default_registry
+from ..obs.trace import trace
 
 __all__ = ["HNSWIndex", "quantized_l2_batch", "KERNEL_DISPATCH_MIN_ELEMS"]
 
@@ -320,28 +321,39 @@ class HNSWIndex:
         against the cached per-vertex norms. Blocks of at least
         ``KERNEL_DISPATCH_MIN_ELEMS`` code elements are first offered to the
         Pallas ``quantized_l2`` kernel via :func:`_offload_distances` (TPU
-        only; the numpy path below is the CPU fast path).
+        only; the numpy path below is the CPU fast path). Smaller blocks
+        count as the seam's ``host`` route. Both routes run under one
+        ``quantized_l2`` span carrying the ``route``, the logical shape
+        (``b``, ``n``, ``d``) and ``operand_bytes`` (float32 queries,
+        uint8 codes, float32 scales, zero-points and mids).
         """
         q2 = np.atleast_2d(np.asarray(queries, dtype=np.float64))
         if n == 0:
             return np.zeros((q2.shape[0], 0), dtype=np.float64)
         _M_DIST_EVALS.inc(q2.shape[0] * n)
-        if n * self.dim >= KERNEL_DISPATCH_MIN_ELEMS:
-            out = _offload_distances(
-                q2, self._codes[:n], self._scales[:n], self._zps[:n],
-                self._mids[:n],
+        b, d = q2.shape
+        with trace("quantized_l2", b=b, n=n, d=d,
+                   operand_bytes=4 * b * d + n * d + 12 * n) as span:
+            if n * self.dim >= KERNEL_DISPATCH_MIN_ELEMS:
+                out = _offload_distances(
+                    q2, self._codes[:n], self._scales[:n], self._zps[:n],
+                    self._mids[:n],
+                )
+                if out is not None:
+                    span.set_attr("route", ops.kernel_route())
+                    out = np.asarray(out, dtype=np.float64)
+                    return np.maximum(out, 0.0, out=out)
+            else:
+                ops.KERNEL_CALLS.labels("quantized_l2", "host").inc()
+            span.set_attr("route", "host")
+            qsq = np.einsum("bd,bd->b", q2, q2)
+            qsum = q2.sum(axis=1)
+            dot = q2.astype(np.float32) @ self._codes[:n].astype(np.float32).T
+            s = self._scales[:n]
+            dist = (qsq[:, None] + self._norms[None, :n]) + 2.0 * (
+                qsum[:, None] * self._cross[None, :n] - s[None, :] * dot
             )
-            if out is not None:
-                out = np.asarray(out, dtype=np.float64)
-                return np.maximum(out, 0.0, out=out)
-        qsq = np.einsum("bd,bd->b", q2, q2)
-        qsum = q2.sum(axis=1)
-        dot = q2.astype(np.float32) @ self._codes[:n].astype(np.float32).T
-        s = self._scales[:n]
-        dist = (qsq[:, None] + self._norms[None, :n]) + 2.0 * (
-            qsum[:, None] * self._cross[None, :n] - s[None, :] * dot
-        )
-        return np.maximum(dist, 0.0, out=dist)
+            return np.maximum(dist, 0.0, out=dist)
 
     def batch_distances(self, query: np.ndarray) -> np.ndarray:
         """Distances from one or many queries to every vertex — the hot loop.

@@ -3,6 +3,12 @@
 These pad inputs to block multiples, pick interpret mode automatically on
 CPU (the kernels TARGET TPU; interpret=True executes the kernel body in
 Python for validation), and slice padding back off.
+
+The dispatch seams time what the host does around a launch with program
+spans (``repro.obs.trace``, docs/observability.md): ``upload`` covers
+operand conversion, padding and host→device transfer up to the kernel's
+dispatch, ``wait`` the time from dispatch until the result is in host
+memory. Neither adds a synchronisation the seam would not make anyway.
 """
 
 from __future__ import annotations
@@ -12,13 +18,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.metrics import default_registry
+from ..obs.trace import trace
 from .dequant_matmul import dequant_matmul_int4_pallas, dequant_matmul_pallas
 from .flash_attention import flash_attention_pallas
 from .quantized_l2 import quantized_l2_pallas
 
 __all__ = ["dequant_matmul", "dequant_matmul_auto", "dequant_matmul_int4",
            "flash_attention", "quantized_l2", "quantized_l2_auto",
-           "pack_int4", "KERNEL_DISPATCH_MIN_ELEMS"]
+           "pack_int4", "kernel_route", "KERNEL_CALLS",
+           "KERNEL_DISPATCH_MIN_ELEMS"]
 
 # Code blocks (N*D elements) below this floor never dispatch to the kernel:
 # the launch + host<->device transfer would swamp the distance math.
@@ -27,7 +35,7 @@ KERNEL_DISPATCH_MIN_ELEMS = 4 << 20
 # Kernel launches per dispatch seam (docs/observability.md). Route "tpu" is
 # the Pallas kernel compiled for the TPU backend, "interpret" the same
 # kernel in interpret mode, "host" the numpy form the seam fell back to.
-_M_KERNEL_CALLS = default_registry().counter(
+KERNEL_CALLS = default_registry().counter(
     "neurstore_kernel_calls_total",
     "Dispatch-seam kernel launches by kernel and route "
     "(tpu / interpret / host).",
@@ -39,7 +47,9 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _kernel_route() -> str:
+def kernel_route() -> str:
+    """The route a kernel launch takes in this process: ``"tpu"`` on a
+    TPU backend, else ``"interpret"``."""
     return "tpu" if _on_tpu() else "interpret"
 
 
@@ -65,35 +75,39 @@ def quantized_l2_auto(queries, codes, scales, zps, mids, *,
 
     ``force="kernel"`` runs the kernel regardless of backend/size (tests
     use this for CPU interpret-mode parity); ``force="numpy"`` always
-    declines.
+    declines. A launch opens one ``upload`` span for the float32 queries
+    and the hoisted code block, then an ``upload`` and a ``wait`` per
+    query row, under the caller's span (``quantized_l2`` in
+    ``HNSWIndex._distance_block``).
     """
     codes = np.asarray(codes)
     if force == "numpy" or (
             force != "kernel" and (not _on_tpu() or codes.size < min_elems)):
-        _M_KERNEL_CALLS.labels("quantized_l2", "host").inc()
+        KERNEL_CALLS.labels("quantized_l2", "host").inc()
         return None
-    q2 = np.atleast_2d(np.asarray(queries, dtype=np.float32))
     n, d = codes.shape
-    if q2.shape[0] == 0:
-        return np.zeros((0, n), dtype=np.float64)
     # Hoist the O(N*D) pad + host→device transfer out of the per-query
     # loop: once padded, the _pad_to calls inside quantized_l2 are no-ops
     # and each iteration is just one (jit-cached) kernel launch. d_true
     # carries the real dimension past the padding.
     bn = _row_block(n, 128)
     bd = min(512, max(128, d)) if d < 512 else 512
-    codes_j = _pad_to(_pad_to(jnp.asarray(codes), bn, 0), bd, 1)
-    s = _pad_to(jnp.asarray(np.asarray(scales, dtype=np.float32)), bn, 0)
-    z = _pad_to(jnp.asarray(np.asarray(zps, dtype=np.float32)), bn, 0)
-    m = _pad_to(jnp.asarray(np.asarray(mids, dtype=np.float32)), bn, 0)
-    _M_KERNEL_CALLS.labels("quantized_l2", _kernel_route()).inc(len(q2))
-    out = [
-        np.asarray(
-            quantized_l2(_pad_to(jnp.asarray(q), bd, 0), codes_j, s, z, m,
-                         d_true=d)
-        )[:n]
-        for q in q2
-    ]
+    with trace("upload"):
+        q2 = np.atleast_2d(np.asarray(queries, dtype=np.float32))
+        if q2.shape[0] == 0:
+            return np.zeros((0, n), dtype=np.float64)
+        codes_j = _pad_to(_pad_to(jnp.asarray(codes), bn, 0), bd, 1)
+        s = _pad_to(jnp.asarray(np.asarray(scales, dtype=np.float32)), bn, 0)
+        z = _pad_to(jnp.asarray(np.asarray(zps, dtype=np.float32)), bn, 0)
+        m = _pad_to(jnp.asarray(np.asarray(mids, dtype=np.float32)), bn, 0)
+    KERNEL_CALLS.labels("quantized_l2", kernel_route()).inc(len(q2))
+    out = []
+    for q in q2:
+        with trace("upload"):
+            y = quantized_l2(_pad_to(jnp.asarray(q), bd, 0), codes_j, s, z, m,
+                             d_true=d)
+        with trace("wait"):
+            out.append(np.asarray(y)[:n])
     return np.stack(out).astype(np.float64)
 
 
@@ -184,24 +198,47 @@ def dequant_matmul_auto(x, base, base_scale, base_zp, delta, delta_scale,
     ``force="kernel"`` runs the Pallas kernel regardless of backend/size
     (interpret mode on CPU — the parity-test hook); ``force="numpy"``
     always takes the decomposed path. Returns (M, N) float32 numpy.
+
+    Each call is one span named by the kernel it routes to
+    (``dequant_matmul`` or ``dequant_matmul_int4``) with its ``route``
+    (``tpu`` / ``interpret`` / ``host``), logical shape (``m``, ``k``,
+    ``n``, ``packed``) and ``operand_bytes`` (float32 activations plus
+    the code operands as passed); a kernel route adds ``upload`` and
+    ``wait`` children.
     """
     if force not in (None, "kernel", "numpy"):
         raise ValueError(f"force must be None, 'kernel' or 'numpy': {force!r}")
     base = np.asarray(base)
+    delta = np.asarray(delta)
     use_kernel = force == "kernel" or (
         force is None and _on_tpu() and base.size >= min_elems)
     fn = dequant_matmul_int4 if packed else dequant_matmul
-    if use_kernel:
-        _M_KERNEL_CALLS.labels(fn.__name__, _kernel_route()).inc()
-        xj = jnp.asarray(np.asarray(x, dtype=np.float32))
-        y = fn(xj, jnp.asarray(base), float(base_scale), float(base_zp),
-               jnp.asarray(delta), float(delta_scale), float(delta_zp))
-        return np.asarray(y, dtype=np.float32)
-    _M_KERNEL_CALLS.labels(fn.__name__, "host").inc()
+    route = kernel_route() if use_kernel else "host"
+    KERNEL_CALLS.labels(fn.__name__, route).inc()
+    x32 = np.asarray(x, dtype=np.float32)
+    m, k = x32.shape
+    with trace(fn.__name__, route=route, m=m, k=k, n=base.shape[1],
+               packed=packed,
+               operand_bytes=x32.nbytes + base.nbytes + delta.nbytes):
+        if use_kernel:
+            with trace("upload"):
+                y = fn(jnp.asarray(x32), jnp.asarray(base), float(base_scale),
+                       float(base_zp), jnp.asarray(delta), float(delta_scale),
+                       float(delta_zp))
+            with trace("wait"):
+                return np.asarray(y, dtype=np.float32)
+        return _dequant_matmul_host(x32, base, base_scale, base_zp, delta,
+                                    delta_scale, delta_zp, packed, scratch)
+
+
+def _dequant_matmul_host(x32, base, base_scale, base_zp, delta, delta_scale,
+                         delta_zp, packed, scratch):
+    """The decomposed CPU form of :func:`dequant_matmul_auto`; ``x32`` is
+    the float32 activation block."""
     ops = scratch.get("cpu") if scratch is not None else None
     if ops is None:
         bf = base.astype(np.float32) * np.float32(base_scale)
-        d = np.asarray(delta)
+        d = delta
         if packed:
             # Unpack nibbles to the (K, N) code grid the decomposition
             # needs; the HBM-traffic win of packing belongs to the TPU
@@ -220,7 +257,6 @@ def dequant_matmul_auto(x, base, base_scale, base_zp, delta, delta_scale,
         if scratch is not None:
             scratch["cpu"] = ops
     wf, c = ops
-    x32 = np.asarray(x, dtype=np.float32)
     y = x32 @ wf
     y += c * x32.sum(axis=1, keepdims=True)
     return y

@@ -42,6 +42,7 @@ import numpy as np
 from ..core.quantize import dequantize_linear, extract_msb, quantize_delta, quantize_linear
 from ..models import decode_step
 from ..models.config import ModelConfig
+from ..obs.trace import trace
 
 __all__ = [
     "DecoderSpec", "MaterializedProvider", "decoder_architecture",
@@ -237,6 +238,11 @@ def greedy_decode(provider, spec: DecoderSpec, prompt: np.ndarray,
     embedding lookup through ``provider.gather_rows`` — the decode loop
     itself owns no weights. Returns (B, steps) int64 tokens, plus the
     per-step (B, steps, V) logits when ``return_logits``.
+
+    The call is one ``generate`` span (``batch``, ``prompt``, ``steps``)
+    with a ``forward`` child per position: ``P - 1 + steps`` of them.
+    A forward's self time is the host math (embedding gather, norms,
+    rope, attention, argmax); its matmuls are the seam's spans.
     """
     prompt = np.atleast_2d(np.asarray(prompt, dtype=np.int64))
     b, p = prompt.shape
@@ -248,22 +254,25 @@ def greedy_decode(provider, spec: DecoderSpec, prompt: np.ndarray,
     logits_trace: list[np.ndarray] = []
     tok = prompt[:, 0]
     pos = 0
-    while len(generated) < steps:
-        x = provider.gather_rows("model.embed_tokens.weight", tok)
-        for li in range(spec.n_layers):
-            x = x + _attn_block(provider, li, x, kc, vc, pos, spec)
-            x = x + _mlp_block(provider, li, x, spec)
-        x = _rms_norm(x, provider.vector("model.norm.weight"), spec.norm_eps)
-        logits = provider.matmul(x, "lm_head.weight")
-        nxt = np.argmax(logits, axis=1)
-        pos += 1
-        if pos < p:
-            tok = prompt[:, pos]
-        else:
-            tok = nxt
-            generated.append(nxt)
-            if return_logits:
-                logits_trace.append(logits)
+    with trace("generate", batch=b, prompt=p, steps=steps):
+        while len(generated) < steps:
+            with trace("forward"):
+                x = provider.gather_rows("model.embed_tokens.weight", tok)
+                for li in range(spec.n_layers):
+                    x = x + _attn_block(provider, li, x, kc, vc, pos, spec)
+                    x = x + _mlp_block(provider, li, x, spec)
+                x = _rms_norm(x, provider.vector("model.norm.weight"),
+                              spec.norm_eps)
+                logits = provider.matmul(x, "lm_head.weight")
+                nxt = np.argmax(logits, axis=1)
+            pos += 1
+            if pos < p:
+                tok = prompt[:, pos]
+            else:
+                tok = nxt
+                generated.append(nxt)
+                if return_logits:
+                    logits_trace.append(logits)
     tokens = np.stack(generated, axis=1)
     if return_logits:
         return tokens, np.stack(logits_trace, axis=1)

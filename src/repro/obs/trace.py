@@ -12,10 +12,23 @@ server-side span tree for the same logical operation.
 Completed **root** spans go two places:
 
 - a bounded in-memory ring (``recent_traces()``), newest last, for
-  ``tools/nstat.py`` and post-hoc debugging;
+  ``tools/nstat.py`` and post-hoc debugging. It is bounded by the
+  number of *spans* it holds (``set_trace_ring_size()``), not of roots,
+  and evicts whole roots, oldest first: a decode request is one root
+  with tens of thousands of spans, an HTTP request one with a handful;
 - the slow-op log: a root span whose elapsed time exceeds
-  ``set_slow_op_threshold()`` emits its full indented span tree at
-  WARNING via ``logging.getLogger("repro.obs.slow")``.
+  ``set_slow_op_threshold()`` emits its indented span tree at WARNING
+  via ``logging.getLogger("repro.obs.slow")``, runs of same-named
+  siblings collapsed to one line each.
+
+While a profiler session collects (``jax.profiler.start_trace``, or a
+capture through ``jax.profiler.start_server``), every span also opens a
+profiler annotation (``TraceMe``, what ``jax.profiler.TraceAnnotation``
+is) under its own name, with the attributes it was opened with as
+metadata, so a TensorBoard or Perfetto profile shows the store's spans
+beside the device's ops on one clock. This module imports no jax: a
+process that has not loaded jax runs no profiler, and without a
+session each span pays one check.
 
 Timing is monotonic (``time.perf_counter``).  ``trace()`` always times —
 even with observability disabled — because engine wall-time reporting
@@ -29,6 +42,7 @@ import contextvars
 import logging
 import os
 import secrets
+import sys
 import threading
 import time
 from collections import deque
@@ -53,8 +67,15 @@ _current: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
     "repro_obs_current_span", default=None
 )
 
+# Default bound of the recent-trace ring, in spans: at a few hundred
+# bytes a span, about 25 to 30 MB. A decode window of ~420 forwards at
+# ~38 spans each fits whole.
+DEFAULT_RING_SPANS = 65_536
+
 _ring_lock = threading.Lock()
-_ring: Deque["Span"] = deque(maxlen=256)
+_ring: Deque[Tuple["Span", int]] = deque()  # (root, spans in its tree)
+_ring_spans = 0
+_ring_capacity = DEFAULT_RING_SPANS
 
 # Seconds; roots slower than this dump their tree to the slow-op log.
 # Default 1.0 s: a full-model save at bench scale sits well under it,
@@ -88,10 +109,26 @@ _slow_ops_total = _metrics.default_registry().counter(
 
 
 def set_trace_ring_size(n: int) -> None:
-    """Resize the recent-trace ring (drops existing entries)."""
-    global _ring
+    """Bound the recent-trace ring at ``n`` spans (drops existing entries).
+
+    Whole roots are evicted, oldest first, until the spans held fit; the
+    newest root is always kept, even one larger than ``n``.
+    """
+    global _ring_spans, _ring_capacity
     with _ring_lock:
-        _ring = deque(maxlen=max(1, int(n)))
+        _ring.clear()
+        _ring_spans = 0
+        _ring_capacity = max(1, int(n))
+
+
+def _ring_append(root: "Span") -> None:
+    global _ring_spans
+    n = root.count()
+    with _ring_lock:
+        _ring.append((root, n))
+        _ring_spans += n
+        while _ring_spans > _ring_capacity and len(_ring) > 1:
+            _ring_spans -= _ring.popleft()[1]
 
 
 def set_slow_op_threshold(seconds: float) -> float:
@@ -109,8 +146,24 @@ def get_slow_op_threshold() -> float:
 def recent_traces(n: Optional[int] = None) -> List["Span"]:
     """Most recent completed root spans, oldest first."""
     with _ring_lock:
-        items = list(_ring)
+        items = [root for root, _ in _ring]
     return items if n is None else items[-n:]
+
+
+_annotation_cls = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation():
+    """The profiler's annotation class while a session collects, else None."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None  # no jax, so no profiler
+        from jax.profiler import TraceAnnotation
+
+        cls = _annotation_cls = TraceAnnotation
+    return cls if cls.is_enabled() else None
 
 
 def _new_trace_id() -> str:
@@ -166,6 +219,7 @@ class Span:
         "end",
         "_token",
         "_recording",
+        "_annot",
     )
 
     def __init__(
@@ -181,12 +235,14 @@ class Span:
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
-        self.attrs: Dict[str, object] = dict(attrs) if attrs else {}
+        # trace() hands over the fresh dict of its keyword arguments.
+        self.attrs: Dict[str, object] = attrs if attrs is not None else {}
         self.children: List[Span] = []
         self.start = time.perf_counter()
         self.end: Optional[float] = None
         self._token: Optional[contextvars.Token] = None
         self._recording = recording
+        self._annot = None
 
     def elapsed(self) -> float:
         """Seconds since start (wall time of the span once closed)."""
@@ -195,6 +251,8 @@ class Span:
     def set_attr(self, key: str, value: object) -> None:
         if self._recording:
             self.attrs[key] = value
+        if self._annot is not None:
+            self._annot.set_metadata(**{key: value})
 
     def traceparent(self) -> str:
         return f"00-{self.trace_id}-{self.span_id}-01"
@@ -203,10 +261,17 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
+        cls = _annotation()
+        if cls is not None:
+            self._annot = cls(self.name, **self.attrs)
+            self._annot.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.end = time.perf_counter()
+        if self._annot is not None:
+            self._annot.__exit__(None, None, None)
+            self._annot = None
         if self._token is not None:
             _current.reset(self._token)
             self._token = None
@@ -221,8 +286,7 @@ class Span:
             self._finish_root()
 
     def _finish_root(self) -> None:
-        with _ring_lock:
-            _ring.append(self)
+        _ring_append(self)
         took = self.elapsed()
         if took >= _slow_threshold_s:
             _slow_ops_total.labels(self.name).inc()
@@ -247,20 +311,33 @@ class Span:
                 return span
         return None
 
+    def count(self) -> int:
+        """Spans in this tree, this one included."""
+        n, stack = 0, [self]
+        while stack:
+            span = stack.pop()
+            n += 1
+            stack.extend(span.children)
+        return n
+
     def format_tree(self, indent: int = 0) -> str:
-        """Indented one-line-per-span rendering (the slow-op log format)."""
+        """Indented rendering, one line per span (the slow-op log format).
+
+        A run of same-named siblings renders as one line with its count
+        and total milliseconds, and the children of the run's spans merge
+        under it by name, so a decode request's thousands of spans print
+        as a few lines.
+        """
+        return "\n".join(_format_group([self], indent))
+
+    def _line(self, indent: int) -> str:
         attrs = ""
         if self.attrs:
             attrs = " " + " ".join(
                 f"{k}={v}" for k, v in sorted(self.attrs.items())
             )
-        lines = [
-            f"{'  ' * indent}- {self.name} {self.elapsed() * 1e3:.3f}ms"
-            f" [{self.span_id}]{attrs}"
-        ]
-        for child in self.children:
-            lines.append(child.format_tree(indent + 1))
-        return "\n".join(lines)
+        return (f"{'  ' * indent}- {self.name} {self.elapsed() * 1e3:.3f}ms"
+                f" [{self.span_id}]{attrs}")
 
     def to_dict(self) -> Dict[str, object]:
         return {
@@ -278,6 +355,40 @@ class Span:
             f"Span({self.name!r}, trace={self.trace_id[:8]}…, "
             f"elapsed={self.elapsed():.6f}s, children={len(self.children)})"
         )
+
+
+def _runs(spans: List[Span]) -> List[List[Span]]:
+    """Consecutive same-named spans, grouped."""
+    out: List[List[Span]] = []
+    for span in spans:
+        if out and out[-1][0].name == span.name:
+            out[-1].append(span)
+        else:
+            out.append([span])
+    return out
+
+
+def _by_name(spans: List[Span]) -> List[List[Span]]:
+    """Spans grouped by name, in order of first appearance."""
+    out: Dict[str, List[Span]] = {}
+    for span in spans:
+        out.setdefault(span.name, []).append(span)
+    return list(out.values())
+
+
+def _format_group(group: List[Span], indent: int) -> List[str]:
+    if len(group) == 1:
+        span = group[0]
+        lines = [span._line(indent)]
+        for run in _runs(span.children):
+            lines += _format_group(run, indent + 1)
+        return lines
+    total_ms = sum(s.elapsed() for s in group) * 1e3
+    lines = [f"{'  ' * indent}- {group[0].name} x{len(group)}"
+             f" {total_ms:.3f}ms total"]
+    for same in _by_name([c for s in group for c in s.children]):
+        lines += _format_group(same, indent + 1)
+    return lines
 
 
 def current_span() -> Optional[Span]:

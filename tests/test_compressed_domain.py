@@ -23,6 +23,7 @@ from repro.launch.compressed_serve import (
     greedy_decode,
     save_decoder,
 )
+from repro.obs.trace import recent_traces
 
 SPEC = DecoderSpec(d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
                    n_layers=2, vocab_size=96)
@@ -66,6 +67,7 @@ def test_compressed_decode_matches_materialized_zero_materialize(
     provider = CompressedModel(lm)
     tokens, logits = greedy_decode(provider, SPEC, PROMPT, 6,
                                    return_logits=True)
+    gen = recent_traces()[-1]
     assert calls["materialize"] == 0
     # Every projection + lm_head + embedding went through the kernel seam;
     # tensor() reconstructed norm gains only — never a kernel-served weight.
@@ -77,7 +79,13 @@ def test_compressed_decode_matches_materialized_zero_materialize(
     assert all("norm" in name for name in tensor_calls)
     np.testing.assert_array_equal(tokens, want_tokens)
     np.testing.assert_allclose(logits, want_logits, rtol=1e-4, atol=1e-4)
-    assert provider.counters["matmul_calls"] > 0
+    # Each matmul of the request is a seam span under its generate root:
+    # 7 a layer plus the LM head, each forward.
+    assert gen.name == "generate"
+    seam = [s for s in gen.walk() if s.name == "dequant_matmul"]
+    forwards = PROMPT.shape[1] - 1 + 6
+    assert len(seam) == forwards * (7 * SPEC.n_layers + 1)
+    assert all(s.attrs["operand_bytes"] > 0 for s in seam)
     lm.close()
 
 

@@ -22,10 +22,16 @@ The registry is process-global, so every assertion is on *deltas*
 around the workload, never absolutes.
 """
 
+import importlib.util
 import json
 import logging
+import os
+import subprocess
+import sys
 import threading
+import time
 import urllib.request
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -38,9 +44,11 @@ from repro.obs.metrics import (
     set_enabled,
 )
 from repro.obs.trace import (
+    DEFAULT_RING_SPANS,
     parse_traceparent,
     recent_traces,
     set_slow_op_threshold,
+    set_trace_ring_size,
     trace,
 )
 from repro.server import ModelStoreServer, StoreClient
@@ -248,6 +256,10 @@ def test_save_report_seconds_comes_from_span(tmp_path):
     assert root.elapsed() - report.seconds < 5e-3
     children = {c.name for c in root.children}
     assert {"probe", "quantize", "commit"} <= children
+    # The probe's SHOULDCOMPRESS pass and each tensor's bit-packing have
+    # spans of their own.
+    assert root.find("probe").find("delta") is not None
+    assert [c.name for c in root.find("quantize").children] == ["encode"] * 3
     eng.close()
 
 
@@ -280,6 +292,189 @@ def test_disabled_mode_records_nothing_but_still_times(tmp_path):
     set_enabled(True)
 
 
+def test_ring_evicts_whole_roots_by_span_count():
+    set_trace_ring_size(10)
+    try:
+        roots = []
+        for i in range(4):
+            with trace(f"root{i}") as root:
+                for _ in range(3):
+                    with trace("child"):
+                        pass
+            roots.append(root)
+        # Four spans a root: two roots fit in ten spans, each kept whole.
+        assert recent_traces() == roots[-2:]
+        assert all(len(r.children) == 3 for r in recent_traces())
+        # The newest root stays even where it alone passes the bound.
+        with trace("big") as big:
+            for _ in range(20):
+                with trace("child"):
+                    pass
+        assert recent_traces() == [big]
+    finally:
+        set_trace_ring_size(DEFAULT_RING_SPANS)
+
+
+def test_format_tree_collapses_runs_of_siblings():
+    with trace("generate", batch=2) as root:
+        for _ in range(3):
+            with trace("forward"):
+                for _ in range(2):
+                    with trace("dequant_matmul"):
+                        with trace("upload"):
+                            pass
+                        with trace("wait"):
+                            pass
+        with trace("tail", at=1):
+            pass
+    lines = root.format_tree().splitlines()
+    assert lines[0].startswith("- generate ") and "batch=2" in lines[0]
+    # A run prints one line with its count and total; the children of
+    # its spans merge under it by name.
+    assert [line.split()[:3] for line in lines[1:-1]] == [
+        ["-", "forward", "x3"],
+        ["-", "dequant_matmul", "x6"],
+        ["-", "upload", "x6"],
+        ["-", "wait", "x6"],
+    ]
+    assert [len(line) - len(line.lstrip()) for line in lines] == \
+        [0, 2, 4, 6, 6, 2]
+    assert lines[1].endswith("ms total")
+    total = sum(f.elapsed() for f in root.children if f.name == "forward")
+    assert float(lines[1].split()[3][:-2]) == pytest.approx(total * 1e3,
+                                                            abs=1e-3)
+    assert lines[-1].startswith("  - tail ") and "at=1" in lines[-1]
+    # tools/nstat.py --traces prints the ring in the same form.
+    spec = importlib.util.spec_from_file_location(
+        "nstat", Path(__file__).resolve().parents[1] / "tools" / "nstat.py")
+    nstat = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(nstat)
+    assert nstat._dump_traces(1) == root.format_tree()
+
+
+def test_spans_are_profiler_host_events(tmp_path):
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with trace("obs.outer", m=8, k=16) as outer:
+            with trace("obs.inner") as inner:
+                time.sleep(0.01)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    events = {e.name: e for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("obs.")}
+    assert set(events) == {"obs.outer", "obs.inner"}
+    for span in (outer, inner):
+        assert abs(events[span.name].duration_ns / 1e9
+                   - span.elapsed()) < 1e-3
+    assert events["obs.outer"].start_ns <= events["obs.inner"].start_ns
+    assert {("m", 8), ("k", 16)} <= set(events["obs.outer"].stats)
+
+
+def test_importing_obs_loads_no_jax():
+    code = ("import sys, repro.obs\n"
+            "with repro.obs.trace('x'):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'repro.obs imported jax'\n")
+    src = Path(__file__).resolve().parents[1] / "src"
+    env = dict(os.environ, PYTHONPATH=str(src))
+    subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                   timeout=120)
+
+
+@pytest.mark.parametrize("force,route", [("numpy", "host"),
+                                         ("kernel", "interpret")])
+def test_dequant_seam_span_carries_route_and_shape(force, route):
+    from repro.kernels.ops import dequant_matmul_auto
+
+    rng = np.random.default_rng(12)
+    x = rng.standard_normal((3, 16)).astype(np.float32)
+    base = rng.integers(-128, 128, (16, 8)).astype(np.int8)
+    delta = rng.integers(-128, 128, (16, 8)).astype(np.int8)
+    before = _value("neurstore_kernel_calls_total",
+                    {"kernel": "dequant_matmul", "route": route})
+    with trace("t") as root:
+        dequant_matmul_auto(x, base, 0.01, 0.0, delta, 0.001, 0.0,
+                            force=force)
+    (span,) = root.children
+    assert span.name == "dequant_matmul"
+    assert span.attrs == {"route": route, "m": 3, "k": 16, "n": 8,
+                          "packed": False,
+                          "operand_bytes": 3 * 16 * 4 + 2 * 16 * 8}
+    assert [c.name for c in span.children] == (
+        [] if route == "host" else ["upload", "wait"])
+    assert _value("neurstore_kernel_calls_total",
+                  {"kernel": "dequant_matmul", "route": route}) == before + 1
+
+
+@pytest.mark.parametrize("route", ["host", "interpret"])
+def test_quantized_l2_span_carries_route_and_shape(route, monkeypatch):
+    from repro.core import hnsw as hnswmod
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(13)
+    idx = hnswmod.HNSWIndex(64, seed=0)
+    for row in rng.normal(0, 1, (40, 64)):
+        idx.insert(row)
+    q = rng.normal(0, 1, (3, 64))
+    if route == "interpret":
+        monkeypatch.setattr(hnswmod, "KERNEL_DISPATCH_MIN_ELEMS", 1)
+        monkeypatch.setattr(
+            hnswmod, "_offload_distances",
+            lambda *a: ops.quantized_l2_auto(*a, force="kernel"))
+    before = _value("neurstore_kernel_calls_total",
+                    {"kernel": "quantized_l2", "route": route})
+    with trace("t") as root:
+        idx.batch_distances(q)
+    (span,) = root.children
+    assert span.name == "quantized_l2"
+    assert span.attrs == {"b": 3, "n": 40, "d": 64, "route": route,
+                          "operand_bytes": 4 * 3 * 64 + 40 * 64 + 12 * 40}
+    # A launch: one upload of the code block, then each query row's.
+    assert [c.name for c in span.children] == (
+        [] if route == "host" else ["upload"] + ["upload", "wait"] * 3)
+    # A block under the size gate counts as the seam's host route, one
+    # call a block; a launch counts one a query row.
+    assert _value("neurstore_kernel_calls_total",
+                  {"kernel": "quantized_l2", "route": route}) == \
+        before + (1 if route == "host" else 3)
+
+
+def test_generate_nests_forwards_and_seam_calls(tmp_path):
+    from repro.core import CompressedModel
+    from repro.launch.compressed_serve import (
+        DecoderSpec,
+        greedy_decode,
+        save_decoder,
+    )
+
+    spec = DecoderSpec(d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
+                       n_layers=2, vocab_size=96)
+    eng = StorageEngine(str(tmp_path))
+    save_decoder(eng, "dec", spec, seed=3)
+    lm = eng.load_model("dec", bits=8)
+    greedy_decode(CompressedModel(lm), spec, np.array([[1, 5, 9]] * 2), 4)
+    gen = recent_traces()[-1]
+    lm.close()
+    eng.close()
+    assert gen.name == "generate"
+    assert gen.attrs == {"batch": 2, "prompt": 3, "steps": 4}
+    assert [c.name for c in gen.children] == ["forward"] * (3 - 1 + 4)
+    for fwd in gen.children:
+        assert [c.name for c in fwd.children] == \
+            ["dequant_matmul"] * (7 * spec.n_layers + 1)
+        assert all(c.attrs["route"] == "host" and c.attrs["m"] == 2
+                   for c in fwd.children)
+
+
 # ------------------------------------------- propagation through the server
 @pytest.fixture
 def served(tmp_path):
@@ -308,7 +503,7 @@ def test_traceparent_client_to_engine(served):
     load = tree.find("engine.load")
     assert load is not None
     # Latency attribution: the documented child phases are all present.
-    assert {"probe", "pool", "snapshot"} <= {c.name for c in load.children}
+    assert {"catalog", "pool", "snapshot"} <= {c.name for c in load.children}
     assert tree.find("page.io") is not None or \
         tree.find("decode") is not None
     client.close()

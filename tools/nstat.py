@@ -16,7 +16,8 @@ hnsw / maintenance / server) and prints ``name{labels} value`` lines,
 plus histogram summaries as ``count`` / ``mean``. ``--watch N`` clears
 and re-renders every N seconds, adding per-interval rates for counters.
 ``--traces`` additionally dumps the recent-trace ring (embedded mode
-only — the ring is per-process).
+only — the ring is per-process), in the slow-op log's form: a run of
+same-named sibling spans prints as one line with its count and total.
 
 ``--space`` switches to the du-style space-accounting view (logical vs
 physical bytes, base/delta/metadata split, compression ratio — see
