@@ -24,6 +24,12 @@ Operand-normalization details the kernels don't know about live here:
 Each matmul is a span of the seam (``dequant_matmul`` or
 ``dequant_matmul_int4``, see ``kernels.ops.dequant_matmul_auto``) that
 carries its route, logical shape and operand bytes.
+
+On the kernel route a weight's codes are put on the device on its first
+matmul and stay there, in the tensor's ``scratch``, until
+:meth:`CompressedModel.close`: the pinned snapshot cannot change them,
+and the cache belongs to one tensor object, so it never serves another
+snapshot's codes.
 """
 
 from __future__ import annotations
@@ -39,7 +45,11 @@ __all__ = ["CompressedModel", "CompressedTensor", "KernelNotReady"]
 
 
 class CompressedTensor:
-    """One weight's kernel-ready operands, built once per serving session."""
+    """One weight's kernel-ready operands, built once per serving session.
+
+    ``scratch`` is the seam's per-weight cache: the host route's
+    pre-scaled float32 weight, or the kernel route's device-resident
+    codes. :meth:`CompressedModel.close` empties it."""
 
     __slots__ = ("name", "shape", "k", "n", "packed", "base", "delta",
                  "base_scale", "base_zp", "delta_scale", "delta_zp",
@@ -88,9 +98,12 @@ class CompressedModel:
     """Serve a :class:`LoadedModel` without materializing float weights.
 
     ``matmul(x, name)`` routes through ``kernels.ops.dequant_matmul_auto``
-    (Pallas on TPU, decomposed gemm on CPU); ``gather_rows`` dequantizes
-    only the requested embedding rows; ``vector`` reconstructs small
-    tensors (norm gains) via ``tensor(name)``. Requires a kernel-ready
+    (Pallas on TPU, decomposed gemm on CPU), whose operands stay cached
+    per weight — on the device on the kernel route — for the life of the
+    session; ``close()`` gives them back with the snapshot.
+    ``gather_rows`` dequantizes only the requested embedding rows;
+    ``vector`` reconstructs small tensors (norm gains) via
+    ``tensor(name)``. Requires a kernel-ready
     handle — open it with ``load_model(name, bits=8)`` (or ``bits=4``);
     full-precision handles raise :class:`KernelNotReady` on first use.
     """
@@ -162,4 +175,8 @@ class CompressedModel:
 
     # ------------------------------------------------------------ lifecycle
     def close(self) -> None:
+        """Drop every weight's cached operands (device memory included),
+        then release the snapshot."""
+        for w in self._weights.values():
+            w.scratch.clear()
         self.lm.close()

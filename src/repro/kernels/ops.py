@@ -9,6 +9,9 @@ spans (``repro.obs.trace``, docs/observability.md): ``upload`` covers
 operand conversion, padding and host→device transfer up to the kernel's
 dispatch, ``wait`` the time from dispatch until the result is in host
 memory. Neither adds a synchronisation the seam would not make anyway.
+Given a caller-owned ``scratch``, ``dequant_matmul_auto`` keeps a
+weight's padded code operands on the device after its first kernel call,
+so a later call uploads only its activations.
 """
 
 from __future__ import annotations
@@ -26,7 +29,7 @@ from .quantized_l2 import quantized_l2_pallas
 __all__ = ["dequant_matmul", "dequant_matmul_auto", "dequant_matmul_int4",
            "flash_attention", "quantized_l2", "quantized_l2_auto",
            "pack_int4", "kernel_route", "KERNEL_CALLS",
-           "KERNEL_DISPATCH_MIN_ELEMS"]
+           "OPERAND_RESIDENCY", "KERNEL_DISPATCH_MIN_ELEMS"]
 
 # Code blocks (N*D elements) below this floor never dispatch to the kernel:
 # the launch + host<->device transfer would swamp the distance math.
@@ -40,6 +43,15 @@ KERNEL_CALLS = default_registry().counter(
     "Dispatch-seam kernel launches by kernel and route "
     "(tpu / interpret / host).",
     ("kernel", "route"),
+)
+
+# Kernel-route calls of dequant_matmul_auto given a scratch dict: "staged"
+# put the weight's code operands on the device, "reused" found them there.
+OPERAND_RESIDENCY = default_registry().counter(
+    "neurstore_operand_residency_total",
+    "dequant_matmul_auto kernel calls that staged a weight's code operands "
+    "on the device (staged) or reused the staged ones (reused).",
+    ("kernel", "event"),
 )
 
 
@@ -121,22 +133,32 @@ def _pad_to(x, mult, axis, value=0):
     return jnp.pad(x, pads, constant_values=value)
 
 
+def _launch(pallas_fn, x, basep, base_scale, base_zp, deltap, delta_scale,
+            delta_zp, *, block_m=128, block_n=128, block_k=128,
+            interpret=None):
+    """Pad ``x`` to the kernel's blocks and launch ``pallas_fn`` on weight
+    operands already padded to them; returns the padded output."""
+    if interpret is None:
+        interpret = not _on_tpu()
+    m = x.shape[0]
+    bm = min(block_m, max(8, m)) if m < block_m else block_m
+    # NOTE: padded K rows contribute dq(0)+dq(0) * x_pad(=0) = 0 because x is
+    # zero-padded along K — weight padding values are irrelevant.
+    xp = _pad_to(_pad_to(x, bm, 0), block_k, 1)
+    return pallas_fn(
+        xp, basep, base_scale, base_zp, deltap, delta_scale, delta_zp,
+        block_m=bm, block_n=block_n, block_k=block_k, interpret=interpret)
+
+
 def dequant_matmul(x, base, base_scale, base_zp, delta, delta_scale, delta_zp,
                    *, block_m=128, block_n=128, block_k=128, interpret=None):
     """y = x @ (dq(base) + dq(delta)), fused; pads to MXU-aligned blocks."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    m, k = x.shape
-    _, n = base.shape
-    bm = min(block_m, max(8, m)) if m < block_m else block_m
-    xp = _pad_to(_pad_to(x, bm, 0), block_k, 1)
+    m, n = x.shape[0], base.shape[1]
     basep = _pad_to(_pad_to(base, block_k, 0), block_n, 1)
     deltap = _pad_to(_pad_to(delta, block_k, 0), block_n, 1)
-    # NOTE: padded K rows contribute dq(0)+dq(0) * x_pad(=0) = 0 because x is
-    # zero-padded along K — weight padding values are irrelevant.
-    y = dequant_matmul_pallas(
-        xp, basep, base_scale, base_zp, deltap, delta_scale, delta_zp,
-        block_m=bm, block_n=block_n, block_k=block_k, interpret=interpret)
+    y = _launch(dequant_matmul_pallas, x, basep, base_scale, base_zp, deltap,
+                delta_scale, delta_zp, block_m=block_m, block_n=block_n,
+                block_k=block_k, interpret=interpret)
     return y[:m, :n]
 
 
@@ -152,18 +174,31 @@ def dequant_matmul_int4(x, base, base_scale, base_zp, packed_delta,
                         delta_scale, delta_zp,
                         *, block_m=128, block_n=128, block_k=128, interpret=None):
     """y = x @ (dq(base) + dq(unpack4(packed))); 1.5 HBM bytes/weight."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    m, k = x.shape
-    _, n = base.shape
-    bm = min(block_m, max(8, m)) if m < block_m else block_m
-    xp = _pad_to(_pad_to(x, bm, 0), block_k, 1)
+    m, n = x.shape[0], base.shape[1]
     basep = _pad_to(_pad_to(base, block_k, 0), block_n, 1)
     packedp = _pad_to(_pad_to(packed_delta, block_k // 2, 0), block_n, 1)
-    y = dequant_matmul_int4_pallas(
-        xp, basep, base_scale, base_zp, packedp, delta_scale, delta_zp,
-        block_m=bm, block_n=block_n, block_k=block_k, interpret=interpret)
+    y = _launch(dequant_matmul_int4_pallas, x, basep, base_scale, base_zp,
+                packedp, delta_scale, delta_zp, block_m=block_m,
+                block_n=block_n, block_k=block_k, interpret=interpret)
     return y[:m, :n]
+
+
+def _stage_operands(base, base_scale, base_zp, delta, delta_scale, delta_zp,
+                    packed, *, block_n=128, block_k=128):
+    """A weight's kernel operands, put on the device once: the codes
+    zero-padded on the host to the kernel's block multiples (the packed
+    int4 delta to half the K block), the scales and zero-points as
+    float32 scalars. Returns the staged tuple, in the kernel's argument
+    order, and the padded code bytes."""
+    def pad(a, rows):
+        return np.pad(a, ((0, -a.shape[0] % rows), (0, -a.shape[1] % block_n)))
+
+    basep = pad(base, block_k)
+    deltap = pad(delta, block_k // 2 if packed else block_k)
+    staged = jax.device_put(
+        (basep, np.float32(base_scale), np.float32(base_zp),
+         deltap, np.float32(delta_scale), np.float32(delta_zp)))
+    return staged, basep.nbytes + deltap.nbytes
 
 
 def dequant_matmul_auto(x, base, base_scale, base_zp, delta, delta_scale,
@@ -195,6 +230,14 @@ def dequant_matmul_auto(x, base, base_scale, base_zp, delta, delta_scale,
     unsigned in [0, 15] with unsigned zero-point). Zero-points/scales are
     scalars matching the code recentring.
 
+    On the kernel route the same ``scratch`` keeps the weight's code
+    operands on the device (``scratch["device"]``): the first call pads
+    them to the kernel's blocks and stages them with their scalars, and
+    every later call uploads only ``x``. The caller owns their lifetime
+    and must drop them when the codes can change
+    (``CompressedModel.close()``). Without ``scratch`` every kernel call
+    pads and uploads its operands afresh.
+
     ``force="kernel"`` runs the Pallas kernel regardless of backend/size
     (interpret mode on CPU — the parity-test hook); ``force="numpy"``
     always takes the decomposed path. Returns (M, N) float32 numpy.
@@ -204,7 +247,11 @@ def dequant_matmul_auto(x, base, base_scale, base_zp, delta, delta_scale,
     (``tpu`` / ``interpret`` / ``host``), logical shape (``m``, ``k``,
     ``n``, ``packed``) and ``operand_bytes`` (float32 activations plus
     the code operands as passed); a kernel route adds ``upload`` and
-    ``wait`` children.
+    ``wait`` children. With ``scratch``, ``upload`` covers converting
+    ``x`` and the dispatch, plus on a weight's first kernel call the
+    staging, whose padded code bytes it carries as ``staged_bytes``;
+    each such call counts ``staged`` or ``reused`` in
+    ``neurstore_operand_residency_total``.
     """
     if force not in (None, "kernel", "numpy"):
         raise ValueError(f"force must be None, 'kernel' or 'numpy': {force!r}")
@@ -212,21 +259,34 @@ def dequant_matmul_auto(x, base, base_scale, base_zp, delta, delta_scale,
     delta = np.asarray(delta)
     use_kernel = force == "kernel" or (
         force is None and _on_tpu() and base.size >= min_elems)
-    fn = dequant_matmul_int4 if packed else dequant_matmul
+    kernel, pallas_fn = (
+        ("dequant_matmul_int4", dequant_matmul_int4_pallas) if packed
+        else ("dequant_matmul", dequant_matmul_pallas))
     route = kernel_route() if use_kernel else "host"
-    KERNEL_CALLS.labels(fn.__name__, route).inc()
+    KERNEL_CALLS.labels(kernel, route).inc()
     x32 = np.asarray(x, dtype=np.float32)
     m, k = x32.shape
-    with trace(fn.__name__, route=route, m=m, k=k, n=base.shape[1],
-               packed=packed,
+    n = base.shape[1]
+    with trace(kernel, route=route, m=m, k=k, n=n, packed=packed,
                operand_bytes=x32.nbytes + base.nbytes + delta.nbytes):
         if use_kernel:
-            with trace("upload"):
-                y = fn(jnp.asarray(x32), jnp.asarray(base), float(base_scale),
-                       float(base_zp), jnp.asarray(delta), float(delta_scale),
-                       float(delta_zp))
+            with trace("upload") as upload:
+                staged = scratch.get("device") if scratch is not None else None
+                if staged is None:
+                    staged, nbytes = _stage_operands(
+                        base, base_scale, base_zp, delta, delta_scale,
+                        delta_zp, packed)
+                    if scratch is not None:
+                        scratch["device"] = staged
+                        upload.set_attr("staged_bytes", nbytes)
+                        OPERAND_RESIDENCY.labels(kernel, "staged").inc()
+                else:
+                    OPERAND_RESIDENCY.labels(kernel, "reused").inc()
+                y = _launch(pallas_fn, jnp.asarray(x32), *staged)
             with trace("wait"):
-                return np.asarray(y, dtype=np.float32)
+                # The staged weights are padded: the slice drops the
+                # padded rows and columns on the host.
+                return np.asarray(y, dtype=np.float32)[:m, :n]
         return _dequant_matmul_host(x32, base, base_scale, base_zp, delta,
                                     delta_scale, delta_zp, packed, scratch)
 

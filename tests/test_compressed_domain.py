@@ -23,7 +23,7 @@ from repro.launch.compressed_serve import (
     greedy_decode,
     save_decoder,
 )
-from repro.obs.trace import recent_traces
+from repro.obs.trace import recent_traces, trace
 
 SPEC = DecoderSpec(d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
                    n_layers=2, vocab_size=96)
@@ -99,6 +99,64 @@ def test_compressed_session_pins_frames_until_close(decoder_engine):
     provider.close()
     eng._drain_released()
     assert eng.page_pool.pinned_bytes() == 0
+
+
+def _residency(event):
+    from repro.obs.metrics import default_registry
+
+    return default_registry().sample_value(
+        "neurstore_operand_residency_total",
+        {"kernel": "dequant_matmul", "event": event}) or 0
+
+
+def test_kernel_route_stages_operands_once_per_weight(decoder_engine):
+    """N kernel calls on one weight stage its codes once and reuse them
+    N - 1 times; every call keeps its upload and wait children, and only
+    the staging call's upload carries staged_bytes."""
+    lm = decoder_engine.load_model("dec", bits=8)
+    model = CompressedModel(lm, force="kernel")
+    x = np.random.default_rng(5).normal(0, 1, (2, SPEC.d_model))
+    before = {e: _residency(e) for e in ("staged", "reused")}
+    calls = 4
+    with trace("t") as root:
+        ys = [model.matmul(x, "lm_head.weight") for _ in range(calls)]
+    assert _residency("staged") == before["staged"] + 1
+    assert _residency("reused") == before["reused"] + calls - 1
+    assert [s.name for s in root.children] == ["dequant_matmul"] * calls
+    for i, seam in enumerate(root.children):
+        assert [c.name for c in seam.children] == ["upload", "wait"]
+        upload = seam.children[0]
+        # (64, 96) int8 base and delta, padded to (128, 128) each.
+        assert upload.attrs == ({"staged_bytes": 2 * 128 * 128} if i == 0
+                                else {})
+    for y in ys[1:]:
+        np.testing.assert_array_equal(y, ys[0])
+    assert ys[0].shape == (2, SPEC.vocab_size)
+    model.close()
+
+
+@pytest.mark.parametrize("force", [None, "numpy"])
+def test_host_route_weight_never_stages(decoder_engine, force):
+    lm = decoder_engine.load_model("dec", bits=8)
+    model = CompressedModel(lm, force=force)
+    before = {e: _residency(e) for e in ("staged", "reused")}
+    greedy_decode(model, SPEC, PROMPT, 2)
+    assert {e: _residency(e) for e in before} == before
+    assert all("device" not in w.scratch and "cpu" in w.scratch
+               for w in model._weights.values())
+    model.close()
+
+
+@pytest.mark.parametrize("force", ["kernel", "numpy"])
+def test_close_empties_every_tensor_scratch(decoder_engine, force):
+    lm = decoder_engine.load_model("dec", bits=8)
+    model = CompressedModel(lm, force=force)
+    greedy_decode(model, SPEC, PROMPT, 1)
+    weights = list(model._weights.values())
+    assert len(weights) == 7 * SPEC.n_layers + 1
+    assert all(w.scratch for w in weights)
+    model.close()
+    assert all(w.scratch == {} for w in weights)
 
 
 def test_full_precision_handle_raises_kernel_not_ready(decoder_engine):

@@ -236,6 +236,43 @@ def test_dequant_matmul_auto_int8_paths_agree():
     np.testing.assert_array_equal(yn, yn2)
 
 
+@pytest.mark.parametrize("packed", [False, True])
+def test_dequant_matmul_auto_staged_operands_bit_identical(packed):
+    """With a scratch dict the kernel route stages the codes on the
+    device, padded once (K and N here are not block multiples); the
+    staging call, a reusing call and a call without scratch all return
+    exactly what the kernel's padding wrapper returns for the same
+    operands."""
+    k, n, m = 192, 200, 3
+    base = RNG.integers(-128, 128, (k, n)).astype(np.int8)
+    if packed:
+        delta = ops.pack_int4(RNG.integers(0, 16, (k, n)).astype(np.uint8))
+        scalars = (0.02, -3.0, 5e-4, 8.0)
+    else:
+        delta = RNG.integers(-128, 128, (k, n)).astype(np.int8)
+        scalars = (0.013, -11.0, 3.1e-4, -64.0)
+    x = RNG.normal(0, 1, (m, k)).astype(np.float32)
+    args = (x, base, scalars[0], scalars[1], delta, scalars[2], scalars[3])
+    wrapper = ops.dequant_matmul_int4 if packed else ops.dequant_matmul
+    want = np.asarray(wrapper(jnp.asarray(x), jnp.asarray(base), scalars[0],
+                              scalars[1], jnp.asarray(delta), scalars[2],
+                              scalars[3]))
+    unstaged = ops.dequant_matmul_auto(*args, packed=packed, force="kernel")
+    scratch: dict = {}
+    staged = ops.dequant_matmul_auto(*args, packed=packed, force="kernel",
+                                     scratch=scratch)
+    basep, _, _, deltap, _, _ = scratch["device"]
+    assert basep.shape == (256, 256) and basep.dtype == np.int8
+    assert deltap.shape == ((128, 256) if packed else (256, 256))
+    assert deltap.dtype == delta.dtype
+    reused = ops.dequant_matmul_auto(*args, packed=packed, force="kernel",
+                                     scratch=scratch)
+    assert want.shape == staged.shape == reused.shape == (m, n)
+    np.testing.assert_array_equal(unstaged, want)
+    np.testing.assert_array_equal(staged, want)
+    np.testing.assert_array_equal(reused, want)
+
+
 def test_dequant_matmul_auto_rejects_bad_force():
     with pytest.raises(ValueError):
         ops.dequant_matmul_auto(

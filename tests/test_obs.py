@@ -102,6 +102,7 @@ CONTRACT = [
     ("neurstore_hnsw_searches_total", "counter", ()),
     ("neurstore_hnsw_inserts_total", "counter", ()),
     ("neurstore_kernel_calls_total", "counter", ("kernel", "route")),
+    ("neurstore_operand_residency_total", "counter", ("kernel", "event")),
     ("neurstore_maintenance_steps_total", "counter", ()),
     ("neurstore_maintenance_errors_total", "counter", ()),
     ("neurstore_maintenance_restarts_total", "counter", ()),
