@@ -62,15 +62,20 @@ def pack_bits_planar(values: np.ndarray, nbit: int) -> bytes:
     """
     if nbit == 0 or values.size == 0:
         return b""
-    v = np.ascontiguousarray(values.ravel(), dtype=np.uint64)
+    # The narrowest unsigned type that holds nbit bits: the shifts below
+    # then move a quarter or an eighth of the bytes a uint64 would.
+    utype = next(t for t in (np.uint8, np.uint16, np.uint32, np.uint64)
+                 if np.iinfo(t).bits >= nbit)
+    v = np.ascontiguousarray(values.ravel(), dtype=utype)
     n = v.size
     plane_nbytes = planar_plane_bytes(n)
-    shifts = np.arange(nbit - 1, -1, -1, dtype=np.uint64)[:, None]
+    shifts = np.arange(nbit - 1, -1, -1, dtype=utype)[:, None]
     out = np.empty((nbit, plane_nbytes), dtype=np.uint8)
     chunk = _PLANE_CHUNK  # multiple of 8 → chunk planes stay byte-aligned
     for start in range(0, n, chunk):
         seg = v[start:start + chunk]
-        bits = ((seg[None, :] >> shifts) & np.uint64(1)).astype(np.uint8)
+        bits = ((seg[None, :] >> shifts) & utype(1)).astype(np.uint8,
+                                                            copy=False)
         out[:, start // 8: start // 8 + (seg.size + 7) // 8] = np.packbits(bits, axis=1)
     return out.tobytes()
 

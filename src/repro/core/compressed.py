@@ -29,7 +29,11 @@ On the kernel route a weight's codes are put on the device on its first
 matmul and stay there, in the tensor's ``scratch``, until
 :meth:`CompressedModel.close`: the pinned snapshot cannot change them,
 and the cache belongs to one tensor object, so it never serves another
-snapshot's codes.
+snapshot's codes. :meth:`CompressedModel.expert_matmul` serves a group
+of same-shape weights (one projection of a MoE layer's held experts)
+through ``kernels.ops.dequant_matmul_group``: one round trip for the
+group, its staged codes kept in a scratch of the group's own, and on the
+host route each weight's own ``scratch``, as a per-weight call keeps it.
 """
 
 from __future__ import annotations
@@ -38,7 +42,12 @@ import math
 
 import numpy as np
 
-from ..kernels.ops import KERNEL_DISPATCH_MIN_ELEMS, dequant_matmul_auto, pack_int4
+from ..kernels.ops import (
+    KERNEL_DISPATCH_MIN_ELEMS,
+    dequant_matmul_auto,
+    dequant_matmul_group,
+    pack_int4,
+)
 from .loader import KernelNotReady, LoadedModel
 
 __all__ = ["CompressedModel", "CompressedTensor", "KernelNotReady"]
@@ -116,6 +125,7 @@ class CompressedModel:
         self.min_elems = min_elems
         self.force = force
         self._weights: dict[str, CompressedTensor] = {}
+        self._groups: dict[tuple[str, ...], dict] = {}
         self._vectors: dict[str, np.ndarray] = {}
         #: Names whose bytes were served through the kernel seam — the
         #: zero-materialize acceptance test asserts ``materialize()`` /
@@ -138,6 +148,20 @@ class CompressedModel:
             x, w.base, w.base_scale, w.base_zp, w.delta, w.delta_scale,
             w.delta_zp, packed=w.packed, min_elems=self.min_elems,
             force=self.force, scratch=w.scratch)
+
+    def expert_matmul(self, x: np.ndarray, names, rows=None) -> np.ndarray:
+        """``x @ dq(weight)`` for each weight of a same-shape group, in
+        one seam call; ``x`` (M, K) shared or (E, M, K) one block per
+        weight, returns (E, M, N). ``rows`` (how many of the M rows the
+        caller routes to each weight) is recorded on the seam's span."""
+        names = tuple(names)
+        ws = [self.weight(n) for n in names]
+        return dequant_matmul_group(
+            x, [(w.base, w.base_scale, w.base_zp, w.delta, w.delta_scale,
+                 w.delta_zp) for w in ws], [w.packed for w in ws],
+            min_elems=self.min_elems, force=self.force,
+            scratch=self._groups.setdefault(names, {}),
+            scratches=[w.scratch for w in ws], rows=rows)
 
     def bytes_per_weight(self, name: str) -> float:
         """Kernel-operand bytes per weight element (2.0 int8, 1.5 int4)."""
@@ -179,4 +203,6 @@ class CompressedModel:
         then release the snapshot."""
         for w in self._weights.values():
             w.scratch.clear()
+        for group in self._groups.values():
+            group.clear()
         self.lm.close()

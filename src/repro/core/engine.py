@@ -916,6 +916,16 @@ class StorageEngine:
         for i in range(0, len(positions), step):
             yield positions[i:i + step]
 
+    @staticmethod
+    def _stack_flats(srcs: list, dim: int) -> np.ndarray:
+        """The (G, dim) float64 block of one probe chunk, written row by
+        row: one conversion pass, where a per-row float64 copy and a
+        stack of the copies make two."""
+        flats = np.empty((len(srcs), dim), dtype=np.float64)
+        for r, src in enumerate(srcs):
+            flats[r] = np.asarray(src).reshape(-1)
+        return flats
+
     def _probe_dim_group(
         self, index: HNSWIndex, flats: np.ndarray, tau_: float
     ) -> tuple[list[tuple[int, np.ndarray]], list[int], list[dict]]:
@@ -988,18 +998,18 @@ class StorageEngine:
                 explains[j] = {"probe_distance": dist}  # completed below
         if not cand_pos:
             return bases, [], explains
-        cand = flats[cand_pos]
+        cand = flats[cand_pos] if len(cand_pos) < g else flats
         qc, qs, qz, qm = quantize_linear_batch(cand, nbit=8)
         deq = dequantize_linear_batch(qc, qs, qz, qm)
         accepted: list[int] = []  # local candidate indices → new bases
         batch_refs: list[int] = []  # group positions resolved after insert
-        acc_mat = np.empty_like(cand)  # dequantized accepted bases, in order
         for local_j, j in enumerate(cand_pos):
             flat = flats[j]
             if accepted:
-                diff = acc_mat[: len(accepted)] - flat
+                diff = deq[accepted]
+                diff -= flat
                 k = int(np.argmin(np.einsum("ad,ad->a", diff, diff)))
-                delta = flat - acc_mat[k]
+                delta = flat - deq[accepted[k]]
                 rng = float(delta.max() - delta.min())
                 if rng <= tau_:
                     bases[j] = (k, delta)  # k resolved to a vid below
@@ -1007,7 +1017,6 @@ class StorageEngine:
                     explains[j].update(
                         outcome="intra_save_dedup", delta_range=rng)
                     continue
-            acc_mat[len(accepted)] = deq[local_j]
             delta = flats[j] - deq[local_j]
             bases[j] = (len(accepted), delta)
             batch_refs.append(j)
@@ -1016,10 +1025,12 @@ class StorageEngine:
                 outcome="new_base",
                 delta_range=float(delta.max() - delta.min()),
             )
-        sel = np.asarray(accepted, dtype=np.int64)
+        if len(accepted) < len(cand_pos):
+            sel = np.asarray(accepted, dtype=np.int64)
+            cand, deq = cand[sel], deq[sel]
+            qc, qs, qz, qm = qc[sel], qs[sel], qz[sel], qm[sel]
         vids = index.insert_batch(
-            cand[sel], quantized=(qc[sel], qs[sel], qz[sel], qm[sel])
-        )
+            cand, quantized=(qc, qs, qz, qm), dequantized=deq)
         for j in batch_refs:
             k, delta = bases[j]
             bases[j] = (vids[k], delta)
@@ -1127,11 +1138,8 @@ class StorageEngine:
                         self._check_quarantine(dim)
                         index = self.index_cache.get(dim, create=True)
                         for chunk in self._iter_group_chunks(positions, dim):
-                            flats = np.stack([
-                                np.asarray(items[pos][2],
-                                           dtype=np.float64).ravel()
-                                for pos in chunk
-                            ])
+                            flats = self._stack_flats(
+                                [items[pos][2] for pos in chunk], dim)
                             group_bases, group_new, group_ex = (
                                 self._probe_dim_group(index, flats, tau_)
                             )
@@ -1365,12 +1373,9 @@ class StorageEngine:
                         self._check_quarantine(dim)
                         index = self.index_cache.get(dim, create=True)
                         for chunk in self._iter_group_chunks(positions, dim):
-                            flats = np.stack([
-                                np.asarray(
-                                    all_items[mi][pos][2], dtype=np.float64
-                                ).ravel()
-                                for mi, pos in chunk
-                            ])
+                            flats = self._stack_flats(
+                                [all_items[mi][pos][2] for mi, pos in chunk],
+                                dim)
                             group_bases, group_new, group_ex = (
                                 self._probe_dim_group(index, flats, tau_)
                             )

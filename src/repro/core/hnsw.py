@@ -89,7 +89,12 @@ import pickle
 
 import numpy as np
 
-from .quantize import QuantMeta, quantize_linear, quantize_linear_batch
+from .quantize import (
+    QuantMeta,
+    dequantize_linear_batch,
+    quantize_linear,
+    quantize_linear_batch,
+)
 from ..kernels import ops
 from ..obs.metrics import default_registry
 from ..obs.trace import trace
@@ -218,6 +223,11 @@ class HNSWIndex:
         self._levels: list[int] = []
         # neighbors[layer][node] -> int64 ndarray of neighbor ids
         self._neighbors: list[dict[int, np.ndarray]] = []
+        # (layer, u) -> (neighbor list, distances from deq(u) to each):
+        # kept beside u's adjacency array by batched linking, valid while
+        # that array is still u's list (lists are replaced, never edited
+        # in place), so a shrink ranks from it and not from a full row.
+        self._nbr_dist: dict[tuple[int, int], tuple[np.ndarray, np.ndarray]] = {}
         self._entry: int | None = None
         self._max_level = -1
 
@@ -275,7 +285,10 @@ class HNSWIndex:
         codes, meta = self.vertex_codes(vid)
         if meta.scale == 0.0:
             return np.full(self.dim, meta.mid, dtype=np.float64)
-        return (codes.astype(np.float64) - meta.zero_point) * meta.scale
+        out = codes.astype(np.float64)
+        out -= meta.zero_point
+        out *= meta.scale
+        return out
 
     # ------------------------------------------------------------- tombstones
     def mark_deleted(self, vid: int) -> None:
@@ -590,58 +603,42 @@ class HNSWIndex:
         return lst
 
     def _backlink_batch(
-        self, layer: int, vid: int, nbrs, adj: dict, m: int, shared: dict
+        self, layer: int, vid: int, nbrs, adj: dict, m: int,
+        srow: np.ndarray, shared: dict,
     ) -> None:
         """Backlink ``vid`` into its selected neighbors — batched shrink.
 
-        Once a vertex has been shrunk its list sits exactly at the degree
-        cap, so every later backlink appends one id; the cached post-shrink
-        distances (``shared['nbr']``) are extended with a single new pair
-        distance instead of recomputing the whole deq(u)-vs-list row — and
-        those pair distances are computed for ALL cache-hit neighbors of
-        this link in one (k, D) gemv against ``vid``'s codes. This was the
-        dominant cost of naive batched linking (every backlink paid a full
-        gather + gemv, ~half the insert_batch wall time).
+        ``srow[u]`` is the distance between the dequantized bases of
+        ``vid`` and ``u``: a row of the batch's distance block queried with
+        the dequantized batch rather than the raw tensors. Appended to the
+        distances kept beside ``u``'s list (``self._nbr_dist``), it lets a
+        list over the degree cap be ranked without recomputing deq(u)
+        against the whole list — at large dims, where the engine inserts a
+        dim group a few tensors a batch, that full row per shrink was most
+        of a save. A list with no kept distances (built by sequential
+        ``insert`` or loaded from disk) is ranked by its full row once,
+        and kept from then on.
         """
-        nbr_cache = shared["nbr"]
-        deq = shared["deq"]
-        hits: list[tuple[int, np.ndarray, np.ndarray]] = []
+        cache = self._nbr_dist
         for u in nbrs:
             cur = adj.get(u, _EMPTY_IDS)
-            if cur.size < m:  # under cap: plain append, no shrink
-                adj[u] = self._append_id(cur, vid)
-                continue
-            hit = nbr_cache.get((layer, u))
+            lst = self._append_id(cur, vid)
+            hit = cache.get((layer, u))
             if hit is not None and hit[0] is cur:
-                hits.append((u, cur, hit[1]))
+                du = np.append(hit[1], srow[u])
+            elif cur.size == 0:
+                du = srow[[u]]
+            elif lst.size <= m:  # under cap, distances unknown: append
+                adj[u] = lst
                 continue
-            # First shrink of u this batch: full row, seeds both caches.
-            lst = self._append_id(cur, vid)
-            u32, usq, usum = self._shrink_query(u, shared)
-            du = self._distances(u32, usq, usum, lst)
-            order = np.argsort(du)[:m]
-            lst = lst[order]
-            nbr_cache[(layer, u)] = (lst, du[order])
+            else:
+                u32, usq, usum = self._shrink_query(u, shared)
+                du = self._distances(u32, usq, usum, lst)
+            if lst.size > m:
+                order = np.argsort(du)[:m]
+                lst, du = lst[order], du[order]
             adj[u] = lst
-        if not hits:
-            return
-        cv = self._codes[vid].astype(np.float32)
-        u32s = np.stack([deq[u][0] for u, _, _ in hits])
-        dots = u32s @ cv  # (k,) — one gemv for every cache-hit shrink
-        nv = float(self._norms[vid])
-        crv = float(self._cross[vid])
-        sv = float(self._scales[vid])
-        for (u, cur, cached), dot in zip(hits, dots.tolist()):
-            _u32, usq, usum = deq[u]
-            d = (usq + nv) + 2.0 * (usum * crv - sv * dot)
-            du = np.empty(cached.size + 1)
-            du[:-1] = cached
-            du[-1] = d if d > 0.0 else 0.0
-            lst = self._append_id(cur, vid)
-            order = np.argsort(du)[:m]
-            lst = lst[order]
-            nbr_cache[(layer, u)] = (lst, du[order])
-            adj[u] = lst
+            cache[(layer, u)] = (lst, du)
 
     def _link(
         self,
@@ -650,19 +647,24 @@ class HNSWIndex:
         q: np.ndarray,
         drow: np.ndarray | None = None,
         shared: dict | None = None,
+        srow: np.ndarray | None = None,
     ) -> None:
         """Wire ``vid`` into the graph (the second half of ``insert``).
 
         Sequential path (``shared is None``): per-item greedy descent from
         the global entry through the upper layers — behaviorally identical
         to the seed insert. Batched path: the upper-layer descent is shared
-        across the batch (:meth:`_batch_chain`) and every candidate
-        distance is a lookup into ``drow``, the batch-wide matrix from
-        :meth:`_distance_block`.
+        across the batch (:meth:`_batch_chain`), every candidate distance
+        is a lookup into ``drow``, the batch-wide matrix from
+        :meth:`_distance_block`, and every distance a shrink needs is one
+        into ``srow`` (see :meth:`_backlink_batch`).
         """
-        q32 = q.astype(np.float32)
-        qsq = float(np.dot(q, q))
-        qsum = float(q.sum())
+        if drow is None:
+            q32 = q.astype(np.float32)
+            qsq = float(np.dot(q, q))
+            qsum = float(q.sum())
+        else:  # every distance is a lookup into drow
+            q32, qsq, qsum = None, 0.0, 0.0
         if shared is None:
             entry = [self._entry]
             for layer in range(self._max_level, level, -1):
@@ -681,7 +683,8 @@ class HNSWIndex:
             adj = self._neighbors[layer]
             adj[vid] = np.asarray(nbrs, dtype=np.int64)
             if shared is not None:
-                self._backlink_batch(layer, vid, nbrs, adj, m, shared)
+                self._nbr_dist[(layer, vid)] = (adj[vid], srow[adj[vid]])
+                self._backlink_batch(layer, vid, nbrs, adj, m, srow, shared)
             else:
                 for u in nbrs:
                     lst = np.append(adj.get(u, _EMPTY_IDS), vid)
@@ -721,6 +724,7 @@ class HNSWIndex:
         tensors,
         quantized: tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray] | None = None,
         max_matrix_elems: int = 1 << 24,
+        dequantized: np.ndarray | None = None,
     ) -> list[int]:
         """Insert a batch of same-dim tensors; returns their vertex ids.
 
@@ -738,7 +742,10 @@ class HNSWIndex:
            ``batch_distances`` matrix of candidate-vs-resident codes: every
            per-candidate distance in the layer searches is an O(1) lookup
            into one (B, N) gemm computed through the kernel dispatch seam
-           (Pallas ``quantized_l2`` on TPU, decomposed numpy gemm on CPU).
+           (Pallas ``quantized_l2`` on TPU, decomposed numpy gemm on CPU);
+           the same block, queried with the dequantized batch too, holds
+           every distance a neighbor's shrink needs (see
+           :meth:`_backlink_batch`).
 
         The graph that results is *not* edge-identical to sequential
         ``insert`` (the shared descent starts items from the centroid's
@@ -747,9 +754,13 @@ class HNSWIndex:
         Level draws consume the RNG in the same per-item order as
         sequential inserts.
 
+        ``dequantized`` may hold the (B, D) dequantized bases of
+        ``quantized``, where the caller has them already.
+
         ``max_matrix_elems`` bounds the resident distance matrix: batches
-        are chunked so no (rows × cols) block exceeds it (~128 MB float64
-        at the default), keeping memory flat for large ingests.
+        are chunked so no (rows × cols) block exceeds twice it (~256 MB
+        float64 at the default, the raw and the dequantized queries),
+        keeping memory flat for large ingests.
         """
         if isinstance(tensors, np.ndarray) and tensors.ndim == 2:
             q_all = np.asarray(tensors, dtype=np.float64)
@@ -789,7 +800,6 @@ class HNSWIndex:
         centroid = q_all.mean(axis=0)
         shared = {
             "deq": {},
-            "nbr": {},
             "centroid": (
                 centroid.astype(np.float32),
                 float(np.dot(centroid, centroid)),
@@ -812,15 +822,22 @@ class HNSWIndex:
             )
             end = min(b, start + max(1, rows_per_chunk))
             ncols = n0 + end
-            dmat = self._distance_block(q_all[start:end], ncols)
+            # One block for both queries (raw rows, then their dequantized
+            # bases): the codes are read, or sent to the kernel, once.
+            deq = (dequantized[start:end] if dequantized is not None else
+                   dequantize_linear_batch(codes[start:end], scales[start:end],
+                                           zps[start:end], mids[start:end]))
+            both = self._distance_block(np.concatenate([q_all[start:end], deq]),
+                                        ncols)
+            dmat, smat = both[:end - start], both[end - start:]
             for i in range(start, end):
                 vid = n0 + i
                 if self._entry is None:
                     self._entry = vid
                     self._max_level = levels[i]
                     continue
-                self._link(vid, levels[i], q_all[i],
-                           drow=dmat[i - start], shared=shared)
+                self._link(vid, levels[i], q_all[i], drow=dmat[i - start],
+                           shared=shared, srow=smat[i - start])
             start = end
         return list(range(n0, n0 + b))
 
@@ -944,6 +961,7 @@ class HNSWIndex:
         while new_layers and not new_layers[-1]:
             new_layers.pop()
         self._neighbors = new_layers
+        self._nbr_dist.clear()
 
         # 4) New entry point: lowest-id survivor on the highest level.
         if nlive == 0:

@@ -98,7 +98,8 @@ def quantize_linear_batch(
     # Fused float path: round yields integral float64 (exact ≤ 2^53), so
     # adding the zero-point and clipping before the single int cast is
     # value-identical to the scalar path's int64 arithmetic.
-    q = np.round(x2 / safe[:, None])
+    q = x2 / safe[:, None]
+    np.round(q, out=q)
     q += zps.astype(np.float64)[:, None]
     np.clip(q, 0, levels, out=q)
     codes = q.astype(np.int64)
@@ -123,7 +124,9 @@ def dequantize_linear_batch(
     c2 = np.atleast_2d(codes)
     s = np.asarray(scales, dtype=np.float64)
     z = np.asarray(zero_points, dtype=np.float64)
-    deq = (c2.astype(np.float64) - z[:, None]) * s[:, None]
+    deq = c2.astype(np.float64)
+    deq -= z[:, None]
+    deq *= s[:, None]
     const = s == 0.0
     if const.any():
         deq[const] = np.asarray(mids, dtype=np.float64)[const, None]
@@ -160,13 +163,17 @@ def quantize_delta(delta: np.ndarray, p: float) -> tuple[np.ndarray, QuantMeta]:
     # stray -1 breaks the |err| <= p guarantee. zp = -floor(dmin/scale) pins
     # q_min to exactly 0 — same quantity up to the paper's off-by-one.
     zero_point = -int(math.floor(dmin / scale))
-    q = np.floor(d64 / scale).astype(np.int64) + zero_point
+    f = d64 / scale
+    np.floor(f, out=f)
+    q = f.astype(np.int64)
+    del f
+    q += zero_point
     qmax = int(q.max())
     while qmax > (1 << nbit) - 1 and nbit < MAX_NBIT:
         # Rare bin-alignment overflow (range/scale lands exactly on a power
         # of two): widen by one bit rather than clip and violate the bound.
         nbit += 1
-    q = np.clip(q, 0, (1 << nbit) - 1)
+    np.clip(q, 0, (1 << nbit) - 1, out=q)
     return q, QuantMeta(scale=scale, zero_point=zero_point, nbit=nbit)
 
 

@@ -11,10 +11,15 @@ dispatch, ``wait`` the time from dispatch until the result is in host
 memory. Neither adds a synchronisation the seam would not make anyway.
 Given a caller-owned ``scratch``, ``dequant_matmul_auto`` keeps a
 weight's padded code operands on the device after its first kernel call,
-so a later call uploads only its activations.
+so a later call uploads only its activations. ``dequant_matmul_group``
+does the same for a group of same-shape weights (a MoE layer's held
+experts): one upload, one jitted launch of a kernel per weight, one
+wait.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +31,8 @@ from .dequant_matmul import dequant_matmul_int4_pallas, dequant_matmul_pallas
 from .flash_attention import flash_attention_pallas
 from .quantized_l2 import quantized_l2_pallas
 
-__all__ = ["dequant_matmul", "dequant_matmul_auto", "dequant_matmul_int4",
+__all__ = ["dequant_matmul", "dequant_matmul_auto", "dequant_matmul_group",
+           "dequant_matmul_int4",
            "flash_attention", "quantized_l2", "quantized_l2_auto",
            "pack_int4", "kernel_route", "KERNEL_CALLS",
            "OPERAND_RESIDENCY", "KERNEL_DISPATCH_MIN_ELEMS"]
@@ -133,6 +139,12 @@ def _pad_to(x, mult, axis, value=0):
     return jnp.pad(x, pads, constant_values=value)
 
 
+def _m_block(m: int, block_m: int = 128) -> int:
+    """Row block of an ``m``-row activation: ``block_m``, or ``m`` (at
+    least 8) when fewer rows exist."""
+    return min(block_m, max(8, m)) if m < block_m else block_m
+
+
 def _launch(pallas_fn, x, basep, base_scale, base_zp, deltap, delta_scale,
             delta_zp, *, block_m=128, block_n=128, block_k=128,
             interpret=None):
@@ -140,8 +152,7 @@ def _launch(pallas_fn, x, basep, base_scale, base_zp, deltap, delta_scale,
     operands already padded to them; returns the padded output."""
     if interpret is None:
         interpret = not _on_tpu()
-    m = x.shape[0]
-    bm = min(block_m, max(8, m)) if m < block_m else block_m
+    bm = _m_block(x.shape[0], block_m)
     # NOTE: padded K rows contribute dq(0)+dq(0) * x_pad(=0) = 0 because x is
     # zero-padded along K — weight padding values are irrelevant.
     xp = _pad_to(_pad_to(x, bm, 0), block_k, 1)
@@ -320,6 +331,120 @@ def _dequant_matmul_host(x32, base, base_scale, base_zp, delta, delta_scale,
     y = x32 @ wf
     y += c * x32.sum(axis=1, keepdims=True)
     return y
+
+
+@functools.partial(jax.jit, static_argnames=("fns", "bm", "interpret"))
+def _group_launch(xp, staged, *, fns, bm, interpret):
+    """One dispatch for a group: each weight's kernel over the shared
+    activation block, or over its own where ``xp`` is 3-D; a tuple of
+    ``(Mp, Np)`` results. Stacking them on the device would fuse every
+    kernel but the first into the stack's update, and the device trace
+    would then name those kernels fusions, not custom calls."""
+    return tuple(
+        fn(xp[i] if xp.ndim == 3 else xp, *ops, block_m=bm, block_n=128,
+           block_k=128, interpret=interpret)
+        for i, (fn, ops) in enumerate(zip(fns, staged)))
+
+
+def dequant_matmul_group(x, operands, packed, *,
+                         min_elems: int = KERNEL_DISPATCH_MIN_ELEMS,
+                         force: str | None = None,
+                         scratch: dict | None = None,
+                         scratches=None, rows=None) -> np.ndarray:
+    """Dispatch seam for a group of same-shape compressed weights, such
+    as one projection of a MoE layer's held experts: ``y[e] = x_e @
+    (dq(base_e) + dq(delta_e))``.
+
+    ``x``: (M, K) float, shared by every weight, or (E, M, K), a block
+    for each. ``operands``: E tuples ``(base, base_scale, base_zp,
+    delta, delta_scale, delta_zp)`` in :func:`dequant_matmul_auto`'s
+    layout; ``packed``: E flags, the int4 layout where set. Returns
+    (E, M, N) float32 numpy.
+
+    The gate is :func:`dequant_matmul_auto`'s, applied to the group's
+    weight elements. On the kernel route the activations upload once,
+    one jitted dispatch launches each weight's Pallas kernel
+    (``dequant_matmul``, or ``dequant_matmul_int4`` where packed), and
+    one wait brings the stacked result back: one host round trip for
+    the group. The caller's ``scratch`` (one per group) keeps every
+    weight's staged codes on the device, as ``dequant_matmul_auto``'s
+    does for one weight. On the host route each weight runs
+    ``dequant_matmul_auto``'s decomposed form with its own entry of
+    ``scratches``, so the result is exactly the per-weight one.
+    ``force`` is ``dequant_matmul_auto``'s.
+
+    The call is one ``dequant_matmul_group`` span with ``route``,
+    ``experts`` (E), ``m``, ``k``, ``n``, ``packed`` (how many weights
+    are int4-packed), ``operand_bytes``, and, where the caller gives
+    ``rows``, ``routed_rows``: how many of the M rows it routes to each
+    weight (the kernel computes all M; the caller weighs the rest 0).
+    The kernel route adds ``upload`` and ``wait`` children as
+    ``dequant_matmul_auto`` does. ``neurstore_kernel_calls_total`` and
+    ``neurstore_operand_residency_total`` count each weight's launch
+    under its kernel.
+    """
+    if force not in (None, "kernel", "numpy"):
+        raise ValueError(f"force must be None, 'kernel' or 'numpy': {force!r}")
+    operands = [tuple(o) for o in operands]
+    packed = [bool(p) for p in packed]
+    bases = [np.asarray(o[0]) for o in operands]
+    deltas = [np.asarray(o[3]) for o in operands]
+    n_w = len(operands)
+    if n_w == 0 or len(packed) != n_w:
+        raise ValueError("a group needs one packed flag per weight, and "
+                         "at least one weight")
+    k, n = bases[0].shape
+    if any(b.shape != (k, n) for b in bases):
+        raise ValueError("a group's weights must share one (K, N) shape")
+    x32 = np.asarray(x, dtype=np.float32)
+    per_weight = x32.ndim == 3
+    if per_weight and x32.shape[0] != n_w:
+        raise ValueError(f"{x32.shape[0]} activation blocks for {n_w} weights")
+    m = x32.shape[-2]
+    use_kernel = force == "kernel" or (
+        force is None and _on_tpu() and n_w * k * n >= min_elems)
+    kernels = ["dequant_matmul_int4" if p else "dequant_matmul" for p in packed]
+    route = kernel_route() if use_kernel else "host"
+    for kernel in kernels:
+        KERNEL_CALLS.labels(kernel, route).inc()
+    attrs = {"route": route, "experts": n_w, "m": m, "k": k, "n": n,
+             "packed": sum(packed),
+             "operand_bytes": x32.nbytes + sum(
+                 b.nbytes + d.nbytes for b, d in zip(bases, deltas))}
+    if rows is not None:
+        attrs["routed_rows"] = [int(r) for r in rows]
+    with trace("dequant_matmul_group", **attrs):
+        if not use_kernel:
+            scratches = scratches or [None] * n_w
+            return np.stack([
+                _dequant_matmul_host(
+                    x32[i] if per_weight else x32, bases[i], o[1], o[2],
+                    deltas[i], o[4], o[5], packed[i], scratches[i])
+                for i, o in enumerate(operands)])
+        with trace("upload") as upload:
+            staged = scratch.get("device") if scratch is not None else None
+            if staged is None:
+                pairs = [_stage_operands(bases[i], o[1], o[2], deltas[i],
+                                         o[4], o[5], packed[i])
+                         for i, o in enumerate(operands)]
+                staged = tuple(p for p, _ in pairs)
+                if scratch is not None:
+                    scratch["device"] = staged
+                    upload.set_attr("staged_bytes", sum(b for _, b in pairs))
+                    for kernel in kernels:
+                        OPERAND_RESIDENCY.labels(kernel, "staged").inc()
+            else:
+                for kernel in kernels:
+                    OPERAND_RESIDENCY.labels(kernel, "reused").inc()
+            bm = _m_block(m)
+            pad = [(0, 0)] * (x32.ndim - 2) + [(0, -m % bm), (0, -k % 128)]
+            y = _group_launch(
+                jnp.asarray(np.pad(x32, pad)), staged,
+                fns=tuple(dequant_matmul_int4_pallas if p
+                          else dequant_matmul_pallas for p in packed),
+                bm=bm, interpret=not _on_tpu())
+        with trace("wait"):
+            return np.stack([a[:m, :n] for a in jax.device_get(y)])
 
 
 def quantized_l2(query, codes, scales, zps, mids,

@@ -3,12 +3,18 @@ weight format (paper §4.3 pushed to the serving fleet).
 
 Two paths share this module:
 
-**Store-backed (the real NeurStore path).** A llama3-shaped decoder
-(GQA + RMSNorm + SwiGLU) is saved through ``StorageEngine.save_model``
-and served straight off the engine: ``load_model(name, bits=8|4)`` →
-:class:`~repro.core.compressed.CompressedModel` → every large matmul of
-:func:`greedy_decode` consumes int8 base codes + int8/int4-packed deltas
-through ``kernels.ops.dequant_matmul_auto``. The snapshot's buffer-pool
+**Store-backed (the real NeurStore path).** A stored decoder is served
+straight off the engine by :func:`greedy_decode`, which picks its blocks
+by spec: a llama3-shaped decoder (:class:`DecoderSpec`: GQA + RMSNorm +
+SwiGLU), or a DeepSeek-V3-shaped one (:class:`DeepseekV3Spec`: latent
+attention, then leading dense layers and expert-parallel MoE layers
+that hold a share of the routed experts). A decoder is saved through
+``StorageEngine.save_model`` and served as ``load_model(name,
+bits=8|4)`` → :class:`~repro.core.compressed.CompressedModel` → every
+large matmul of :func:`greedy_decode` consumes int8 base codes +
+int8/int4-packed deltas through ``kernels.ops.dequant_matmul_auto`` (a
+MoE layer's held experts through ``dequant_matmul_group``, one call a
+projection). The snapshot's buffer-pool
 frame stays pinned for the serving session and ``materialize()`` is never
 called on kernel-served tensors — HBM traffic per weight element drops
 from 2.0 bytes (bf16) to 2.0 (int8 base + int8 delta) or 1.5 (int8 +
@@ -34,6 +40,7 @@ agrees with the materialized forward pass (tests/test_compressed_domain.py).
 from __future__ import annotations
 
 import dataclasses
+from typing import ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -42,10 +49,12 @@ import numpy as np
 from ..core.quantize import dequantize_linear, extract_msb, quantize_delta, quantize_linear
 from ..models import decode_step
 from ..models.config import ModelConfig
+from ..obs.metrics import default_registry
 from ..obs.trace import trace
 
 __all__ = [
-    "DecoderSpec", "MaterializedProvider", "decoder_architecture",
+    "DecoderSpec", "DeepseekV3Spec", "MOE_ASSIGNMENTS",
+    "MaterializedProvider", "decoder_architecture",
     "greedy_decode", "init_decoder_tensors", "save_decoder",
     "spec_from_architecture", "quantize_params", "quantize_leaf",
     "dequantize_leaf_jnp", "make_compressed_serve_step",
@@ -56,6 +65,16 @@ __all__ = [
 MIN_QUANT_SIZE = 65_536
 DELTA_BITS = 4
 
+# Token-expert assignments of the MoE layers' routers, by whether the
+# model being served holds the chosen expert (expert parallelism: the
+# absent ones are other chips' share).
+MOE_ASSIGNMENTS = default_registry().counter(
+    "neurstore_moe_assignments_total",
+    "Token-expert assignments chosen by MoE routers, to experts the served "
+    "model holds (held) or not (absent).",
+    ("placement",),
+)
+
 
 # --------------------------------------------------------------------------
 # Store-backed serving: llama3-shaped decoder over StorageEngine weights
@@ -64,6 +83,8 @@ DELTA_BITS = 4
 @dataclasses.dataclass(frozen=True)
 class DecoderSpec:
     """Shape of the stored decoder (llama3 family, GQA)."""
+
+    kind: ClassVar[str] = "llama3_decoder"
 
     d_model: int = 256
     n_heads: int = 4
@@ -79,14 +100,56 @@ class DecoderSpec:
         return self.d_model // self.n_heads
 
 
-def decoder_architecture(spec: DecoderSpec) -> dict:
+@dataclasses.dataclass(frozen=True)
+class DeepseekV3Spec:
+    """Shape of a stored DeepSeek-V3-family decoder (HF ``deepseek_v3``):
+    latent attention (MLA) with no query LoRA, the first
+    ``first_k_dense`` layers dense SwiGLUs, the rest MoE layers (every
+    width but the attention's is read off the stored weights). A MoE layer's router scores all ``n_experts`` experts
+    (sigmoid), chooses ``top_k`` on score plus its
+    ``e_score_correction_bias`` (one group, so no group selection),
+    and weighs them by their unbiased scores, normalised when
+    ``norm_topk_prob``, times ``routed_scaling_factor``. The served
+    model holds the routed experts ``held_experts`` (expert
+    parallelism: the rest are other chips' share, and their part of
+    the result is theirs) and the shared experts, one SwiGLU."""
+
+    kind: ClassVar[str] = "deepseek_v3"
+
+    d_model: int = 256
+    n_heads: int = 4
+    n_layers: int = 3
+    vocab_size: int = 512
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    first_k_dense: int = 1
+    n_experts: int = 16
+    held_experts: tuple = tuple(range(4))
+    top_k: int = 4
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-5
+
+
+_SPECS = {s.kind: s for s in (DecoderSpec, DeepseekV3Spec)}
+
+
+def decoder_architecture(spec) -> dict:
     """Catalog ``architecture`` payload for a saved decoder."""
-    return {"kind": "llama3_decoder", **dataclasses.asdict(spec)}
+    return {"kind": spec.kind, **dataclasses.asdict(spec)}
 
 
-def spec_from_architecture(arch: dict) -> DecoderSpec:
-    fields = {f.name for f in dataclasses.fields(DecoderSpec)}
-    return DecoderSpec(**{k: v for k, v in dict(arch).items() if k in fields})
+def spec_from_architecture(arch: dict):
+    arch = dict(arch)
+    cls = _SPECS[arch.get("kind", DecoderSpec.kind)]
+    fields = {f.name for f in dataclasses.fields(cls)}
+    out = {k: v for k, v in arch.items() if k in fields}
+    if "held_experts" in out:
+        out["held_experts"] = tuple(out["held_experts"])
+    return cls(**out)
 
 
 def init_decoder_tensors(spec: DecoderSpec, seed: int = 0) -> dict:
@@ -218,53 +281,182 @@ def _attn_block(provider, li: int, x: np.ndarray, kc, vc, pos: int,
     return provider.matmul(o, pre + "self_attn.o_proj.weight")
 
 
-def _mlp_block(provider, li: int, x: np.ndarray, spec: DecoderSpec) -> np.ndarray:
+def _swiglu(provider, xn: np.ndarray, pre: str) -> np.ndarray:
+    gate = provider.matmul(xn, pre + "gate_proj.weight")
+    up = provider.matmul(xn, pre + "up_proj.weight")
+    return provider.matmul(_silu(gate) * up, pre + "down_proj.weight")
+
+
+def _mlp_block(provider, li: int, x: np.ndarray, spec) -> np.ndarray:
     pre = f"model.layers.{li}."
     xn = _rms_norm(x, provider.vector(pre + "post_attention_layernorm.weight"),
                    spec.norm_eps)
-    gate = provider.matmul(xn, pre + "mlp.gate_proj.weight")
-    up = provider.matmul(xn, pre + "mlp.up_proj.weight")
-    return provider.matmul(_silu(gate) * up, pre + "mlp.down_proj.weight")
+    return _swiglu(provider, xn, pre + "mlp.")
 
 
-def greedy_decode(provider, spec: DecoderSpec, prompt: np.ndarray,
-                  steps: int, return_logits: bool = False):
+def _mla_block(provider, li: int, x: np.ndarray, cache: dict, pos: int,
+               spec: DeepseekV3Spec) -> np.ndarray:
+    """Latent attention at one position. The cache is held expanded, as
+    HF's ``deepseek_v3`` holds it: the new token's normed latent is
+    up-projected through ``kv_b_proj``, and its per-head no-rope keys
+    and values are cached beside the one rope key all heads share."""
+    b, h = x.shape[0], spec.n_heads
+    nope, rope, dv = (spec.qk_nope_head_dim, spec.qk_rope_head_dim,
+                      spec.v_head_dim)
+    pre = f"model.layers.{li}."
+    att = pre + "self_attn."
+    with trace("mla"):
+        xn = _rms_norm(x, provider.vector(pre + "input_layernorm.weight"),
+                       spec.norm_eps)
+        q = provider.matmul(xn, att + "q_proj.weight").reshape(b, h, nope + rope)
+        kv_a = provider.matmul(xn, att + "kv_a_proj_with_mqa.weight")
+        latent = _rms_norm(kv_a[:, :spec.kv_lora_rank],
+                           provider.vector(att + "kv_a_layernorm.weight"),
+                           spec.norm_eps)
+        kv = provider.matmul(latent, att + "kv_b_proj.weight").reshape(
+            b, h, nope + dv)
+        k_nope, k_rope, v = cache["k_nope"][li], cache["k_rope"][li], cache["v"][li]
+        k_nope[:, :, pos] = kv[..., :nope]
+        v[:, :, pos] = kv[..., nope:]
+        k_rope[:, pos] = _rope(kv_a[:, spec.kv_lora_rank:], pos, spec.rope_theta)
+        q_rope = _rope(q[..., nope:], pos, spec.rope_theta)
+        # (b, h, 1, d) @ (b, h, d, t): one small gemm per sequence and head.
+        s = (q[..., None, :nope] @ k_nope[:, :, :pos + 1].swapaxes(-1, -2)
+             )[:, :, 0]
+        s += q_rope @ k_rope[:, :pos + 1].swapaxes(-1, -2)
+        s /= np.sqrt(nope + rope)
+        o = (_softmax(s)[:, :, None] @ v[:, :, :pos + 1])[:, :, 0]
+        return provider.matmul(o.reshape(b, h * dv), att + "o_proj.weight")
+
+
+def _route(provider, pre: str, xn: np.ndarray, spec: DeepseekV3Spec):
+    """``(ids, weights, biased)``: each token's ``top_k`` experts out of
+    all ``n_experts``, chosen on sigmoid score plus the correction bias,
+    their weights (the unbiased scores, normalised, scaled), and the
+    biased scores the choice was made on."""
+    logits = provider.matmul(xn, pre + "mlp.gate.weight")
+    scores = 1.0 / (1.0 + np.exp(-logits))
+    biased = scores + provider.vector(pre + "mlp.gate.e_score_correction_bias")
+    ids = np.argsort(-biased, axis=-1, kind="stable")[:, :spec.top_k]
+    w = np.take_along_axis(scores, ids, axis=-1)
+    if spec.top_k > 1 and spec.norm_topk_prob:
+        w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    return ids, w * np.float32(spec.routed_scaling_factor), biased
+
+
+def _moe_block(provider, li: int, x: np.ndarray, spec: DeepseekV3Spec,
+               routing: list | None) -> np.ndarray:
+    """One MoE layer's share: the router over every expert, this model's
+    held experts' part of the routed result, and the shared experts.
+    Each held expert runs over the whole batch, in one grouped seam call
+    a projection; rows not routed to it are combined with weight 0."""
+    pre = f"model.layers.{li}."
+    xn = _rms_norm(x, provider.vector(pre + "post_attention_layernorm.weight"),
+                   spec.norm_eps)
+    held = np.asarray(spec.held_experts)
+    with trace("route"):
+        ids, w, biased = _route(provider, pre, xn, spec)
+        hit = ids[..., None] == held  # (b, top_k, held)
+        combine = np.einsum("bk,bke->be", w, hit).astype(np.float32)
+        n_held = int(hit.sum())
+        MOE_ASSIGNMENTS.labels("held").inc(n_held)
+        MOE_ASSIGNMENTS.labels("absent").inc(ids.size - n_held)
+    if routing is not None:
+        routing.append((ids, biased))
+    names = [f"{pre}mlp.experts.{e}." for e in spec.held_experts]
+    with trace("experts"):
+        rows = hit.any(axis=1).sum(axis=0)
+        gate = provider.expert_matmul(xn, [n + "gate_proj.weight" for n in names],
+                                      rows=rows)
+        up = provider.expert_matmul(xn, [n + "up_proj.weight" for n in names],
+                                    rows=rows)
+        y = provider.expert_matmul(_silu(gate) * up,
+                                   [n + "down_proj.weight" for n in names],
+                                   rows=rows)
+        routed = np.einsum("be,ebd->bd", combine, y)
+    return routed + _swiglu(provider, xn, pre + "mlp.shared_experts.")
+
+
+def _llama_layers(provider, x, cache, pos, spec, routing):
+    for li in range(spec.n_layers):
+        x = x + _attn_block(provider, li, x, cache["k"], cache["v"], pos, spec)
+        x = x + _mlp_block(provider, li, x, spec)
+    return x
+
+
+def _deepseek_layers(provider, x, cache, pos, spec, routing):
+    for li in range(spec.n_layers):
+        x = x + _mla_block(provider, li, x, cache, pos, spec)
+        if li < spec.first_k_dense:
+            x = x + _mlp_block(provider, li, x, spec)
+        else:
+            x = x + _moe_block(provider, li, x, spec, routing)
+    return x
+
+
+def _new_cache(spec, b: int, total: int) -> dict:
+    """Zeroed per-layer caches of ``b`` sequences of ``total`` positions."""
+    def zeros(*shape):
+        return np.zeros((spec.n_layers, b, *shape), np.float32)
+
+    if isinstance(spec, DeepseekV3Spec):
+        return {"k_nope": zeros(spec.n_heads, total, spec.qk_nope_head_dim),
+                "k_rope": zeros(total, spec.qk_rope_head_dim),
+                "v": zeros(spec.n_heads, total, spec.v_head_dim)}
+    return {"k": zeros(spec.n_kv_heads, total, spec.head_dim),
+            "v": zeros(spec.n_kv_heads, total, spec.head_dim)}
+
+
+def greedy_decode(provider, spec, prompt: np.ndarray, steps: int,
+                  return_logits: bool = False, return_routing: bool = False):
     """Greedy decode ``steps`` tokens after consuming ``prompt`` (B, P).
 
     ``provider`` is anything with the matmul/gather_rows/vector interface
     (:class:`~repro.core.compressed.CompressedModel` for compressed-domain
-    serving, :class:`MaterializedProvider` for the float baseline). Every
-    projection and the LM head go through ``provider.matmul``; the
-    embedding lookup through ``provider.gather_rows`` — the decode loop
-    itself owns no weights. Returns (B, steps) int64 tokens, plus the
-    per-step (B, steps, V) logits when ``return_logits``.
+    serving, :class:`MaterializedProvider` for the float baseline), and
+    ``expert_matmul`` for a spec with MoE layers. Every projection and
+    the LM head go through ``provider.matmul``, the held experts through
+    ``provider.expert_matmul``; the embedding lookup through
+    ``provider.gather_rows`` — the decode loop itself owns no weights.
+    ``spec`` picks the blocks: :class:`DecoderSpec` or
+    :class:`DeepseekV3Spec`. Returns (B, steps) int64 tokens, then the
+    per-step (B, steps, V) logits when ``return_logits``, then, when
+    ``return_routing`` (MoE specs only), each MoE layer's routing at
+    every position consumed: ``{"ids": (L_moe, B, P - 1 + steps,
+    top_k), "scores": (L_moe, B, P - 1 + steps, n_experts)}``, the
+    chosen experts and the biased scores they were chosen on.
 
     The call is one ``generate`` span (``batch``, ``prompt``, ``steps``)
     with a ``forward`` child per position: ``P - 1 + steps`` of them.
     A forward's self time is the host math (embedding gather, norms,
-    rope, attention, argmax); its matmuls are the seam's spans.
+    rope, attention, argmax); its matmuls are the seam's spans. Under a
+    :class:`DeepseekV3Spec` a forward holds an ``mla`` span a layer and
+    a ``route`` and an ``experts`` span a MoE layer.
     """
+    moe = isinstance(spec, DeepseekV3Spec)
+    if return_routing and not moe:
+        raise ValueError("return_routing needs a spec with MoE layers")
+    layers = _deepseek_layers if moe else _llama_layers
     prompt = np.atleast_2d(np.asarray(prompt, dtype=np.int64))
     b, p = prompt.shape
-    total = p + steps
-    shape = (spec.n_layers, b, spec.n_kv_heads, total, spec.head_dim)
-    kc = np.zeros(shape, np.float32)
-    vc = np.zeros(shape, np.float32)
+    cache = _new_cache(spec, b, p + steps)
     generated: list[np.ndarray] = []
     logits_trace: list[np.ndarray] = []
+    routing_trace: list[list] = []
     tok = prompt[:, 0]
     pos = 0
     with trace("generate", batch=b, prompt=p, steps=steps):
         while len(generated) < steps:
+            routing = [] if return_routing else None
             with trace("forward"):
                 x = provider.gather_rows("model.embed_tokens.weight", tok)
-                for li in range(spec.n_layers):
-                    x = x + _attn_block(provider, li, x, kc, vc, pos, spec)
-                    x = x + _mlp_block(provider, li, x, spec)
+                x = layers(provider, x, cache, pos, spec, routing)
                 x = _rms_norm(x, provider.vector("model.norm.weight"),
                               spec.norm_eps)
                 logits = provider.matmul(x, "lm_head.weight")
                 nxt = np.argmax(logits, axis=1)
+            if return_routing:
+                routing_trace.append(routing)
             pos += 1
             if pos < p:
                 tok = prompt[:, pos]
@@ -273,10 +465,15 @@ def greedy_decode(provider, spec: DecoderSpec, prompt: np.ndarray,
                 generated.append(nxt)
                 if return_logits:
                     logits_trace.append(logits)
-    tokens = np.stack(generated, axis=1)
+    out = [np.stack(generated, axis=1)]
     if return_logits:
-        return tokens, np.stack(logits_trace, axis=1)
-    return tokens
+        out.append(np.stack(logits_trace, axis=1))
+    if return_routing:
+        # routing_trace[position][layer] = (ids (B, k), scores (B, E)).
+        out.append({key: np.stack([np.stack([layer[j] for layer in step])
+                                   for step in routing_trace], axis=2)
+                    for j, key in enumerate(("ids", "scores"))})
+    return out[0] if len(out) == 1 else tuple(out)
 
 
 # --------------------------------------------------------------------------

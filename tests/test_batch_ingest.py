@@ -234,6 +234,62 @@ def test_insert_batch_incremental_and_chunked():
     ]
 
 
+def test_shrink_distances_kept_across_batches():
+    """A vertex's post-shrink neighbor distances outlive the batch that
+    computed them: inserting in batches of two (as the engine does at
+    large dims) builds the graph a per-batch recomputation builds, with
+    fewer full-row distance computations."""
+    rng = np.random.default_rng(8)
+    dim = 64
+    data = rng.normal(0, 1, (60, dim))
+    kept = HNSWIndex(dim, m=3, ef_construction=16, seed=2)
+    fresh = HNSWIndex(dim, m=3, ef_construction=16, seed=2)
+    rows = {"kept": 0, "fresh": 0}
+    for idx, key in ((kept, "kept"), (fresh, "fresh")):
+        inner = idx._distances
+
+        def counted(*args, _inner=inner, _key=key):
+            rows[_key] += 1
+            return _inner(*args)
+
+        idx._distances = counted
+    for i in range(0, len(data), 2):
+        kept.insert_batch(data[i:i + 2])
+        fresh.insert_batch(data[i:i + 2])
+        fresh._nbr_dist.clear()
+    assert kept._nbr_dist
+    assert rows["kept"] < rows["fresh"] / 2
+    for a, b in zip(kept._neighbors, fresh._neighbors):
+        assert a.keys() == b.keys()
+        for v in a:
+            np.testing.assert_array_equal(a[v], b[v])
+    kept.compact()  # no tombstones: nothing rebuilt, the cache stands
+    for vid in range(5):
+        kept.mark_deleted(vid)
+    kept.compact()
+    assert not kept._nbr_dist
+
+
+def test_insert_batch_takes_callers_dequantized_rows():
+    """Rows the caller has dequantized already (the engine's candidates)
+    build the graph that rows dequantized inside ``insert_batch`` build."""
+    rng = np.random.default_rng(9)
+    dim = 40
+    data = rng.normal(0, 1, (50, dim))
+    own = HNSWIndex(dim, m=3, ef_construction=16, seed=4)
+    given = HNSWIndex(dim, m=3, ef_construction=16, seed=4)
+    for i in range(0, len(data), 5):
+        q = quantize_linear_batch(data[i:i + 5], nbit=8)
+        own.insert_batch(data[i:i + 5], quantized=q)
+        given.insert_batch(data[i:i + 5], quantized=q,
+                           dequantized=dequantize_linear_batch(*q))
+    for a, b in zip(own._neighbors, given._neighbors):
+        assert a.keys() == b.keys()
+        for v in a:
+            np.testing.assert_array_equal(a[v], b[v])
+    assert own._nbr_dist.keys() == given._nbr_dist.keys()
+
+
 def test_insert_batch_levels_match_sequential_rng():
     """Level draws consume the RNG in per-item order: same seed → same
     level assignment as sequential inserts."""

@@ -289,7 +289,7 @@ def _unpack_planar_loop(data, nbit, count, b=None):
     return acc
 
 
-@pytest.mark.parametrize("nbit", [1, 7, 8, 17, 32])
+@pytest.mark.parametrize("nbit", [1, 7, 8, 9, 16, 17, 32, 33])
 @pytest.mark.parametrize("count", [1, 5, 8, 257])
 def test_planar_pack_matches_loop_reference(nbit, count):
     rng = np.random.default_rng(nbit * 100 + count)
