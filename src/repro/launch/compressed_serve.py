@@ -53,13 +53,21 @@ from ..obs.metrics import default_registry
 from ..obs.trace import trace
 
 __all__ = [
-    "DecoderSpec", "DeepseekV3Spec", "MOE_ASSIGNMENTS",
-    "MaterializedProvider", "decoder_architecture",
+    "DECODE_POSITIONS", "DecoderSpec", "DeepseekV3Spec", "MOE_ASSIGNMENTS",
+    "MaterializedProvider", "PREFILL_ROWS", "decoder_architecture",
     "greedy_decode", "init_decoder_tensors", "save_decoder",
     "spec_from_architecture", "quantize_params", "quantize_leaf",
     "dequantize_leaf_jnp", "make_compressed_serve_step",
     "compressed_param_specs",
 ]
+
+#: Rows a prompt forward carries: :func:`greedy_decode` consumes a prompt
+#: in chunks of ``max(1, PREFILL_ROWS // batch)`` positions. A forward's
+#: fixed cost (a host round trip a kernel-routed matmul, and the host code
+#: around them) is paid once a chunk; the kernel reads the same code bytes
+#: at any row count while it is memory-bound (see PERF.md for the sweep it
+#: was set from).
+PREFILL_ROWS = 256
 
 # Leaves smaller than this stay raw (norm vectors, biases).
 MIN_QUANT_SIZE = 65_536
@@ -73,6 +81,14 @@ MOE_ASSIGNMENTS = default_registry().counter(
     "Token-expert assignments chosen by MoE routers, to experts the served "
     "model holds (held) or not (absent).",
     ("placement",),
+)
+
+DECODE_POSITIONS = default_registry().counter(
+    "neurstore_decode_positions_total",
+    "Positions consumed by greedy decode, summed over a batch's sequences: "
+    "the prompt's (prefill), generated tokens fed back (decode), and the "
+    "filler that pads a prompt's last chunk (padding).",
+    ("phase",),
 )
 
 
@@ -212,6 +228,12 @@ class MaterializedProvider:
         c["fused_elems"] += w.size
         return np.asarray(x, np.float32) @ w
 
+    def expert_matmul(self, x: np.ndarray, names, rows=None) -> np.ndarray:
+        """:meth:`matmul` for each weight of a group; ``x`` (M, K) shared
+        or (E, M, K) one block per weight, returns (E, M, N)."""
+        return np.stack([self.matmul(x[i] if x.ndim == 3 else x, name)
+                         for i, name in enumerate(names)])
+
     def gather_rows(self, name: str, ids: np.ndarray) -> np.ndarray:
         rows = self.params[name][np.asarray(ids)]
         self.counters["gather_calls"] += 1
@@ -234,21 +256,17 @@ def _rms_norm(x: np.ndarray, gamma: np.ndarray, eps: float) -> np.ndarray:
     return (x / np.sqrt(ms + eps)) * gamma
 
 
-def _softmax(x: np.ndarray) -> np.ndarray:
-    m = x.max(axis=-1, keepdims=True)
-    e = np.exp(x - m)
-    return e / e.sum(axis=-1, keepdims=True)
-
-
 def _silu(x: np.ndarray) -> np.ndarray:
     return x / (1.0 + np.exp(-x))
 
 
-def _rope(x: np.ndarray, pos: int, theta: float) -> np.ndarray:
-    """Interleaved-pair rotary embedding at one position; x (..., dh)."""
+def _rope(x: np.ndarray, pos: np.ndarray, theta: float) -> np.ndarray:
+    """Interleaved-pair rotary embedding; x (b, T, ..., dh) at the T
+    positions ``pos``."""
     dh = x.shape[-1]
     inv = theta ** (-np.arange(0, dh, 2, dtype=np.float32) / dh)
-    ang = pos * inv
+    ang = np.multiply.outer(np.asarray(pos, np.float32), inv)
+    ang = ang.reshape(len(pos), *(1,) * (x.ndim - 3), dh // 2)
     cos, sin = np.cos(ang), np.sin(ang)
     x1, x2 = x[..., 0::2], x[..., 1::2]
     out = np.empty_like(x)
@@ -257,27 +275,47 @@ def _rope(x: np.ndarray, pos: int, theta: float) -> np.ndarray:
     return out
 
 
-def _attn_block(provider, li: int, x: np.ndarray, kc, vc, pos: int,
-                spec: DecoderSpec) -> np.ndarray:
-    b = x.shape[0]
+def _attend(s: np.ndarray, vals: np.ndarray, pos: np.ndarray) -> np.ndarray:
+    """Causal attention of a run of queries: scores ``s`` (..., T, S) of
+    the queries at positions ``pos`` over the cache's first S positions,
+    softmaxed over the keys each query may see (position t sees keys at
+    positions <= t), applied to ``vals`` (..., S, dv). Overwrites ``s``.
+    Only the run's own keys, the last columns, can lie ahead of a query."""
+    tail = s[..., pos[0]:]
+    tail[..., np.arange(tail.shape[-1]) > np.arange(len(pos))[:, None]] = -np.inf
+    s -= s.max(axis=-1, keepdims=True)
+    np.exp(s, out=s)
+    s /= s.sum(axis=-1, keepdims=True)
+    return s @ vals
+
+
+def _attn_block(provider, li: int, x: np.ndarray, kc, vc, pos: np.ndarray,
+                n: int, spec: DecoderSpec) -> np.ndarray:
+    """GQA over a run: ``x`` (b*T, d) holds each sequence's T positions
+    ``pos``, the first ``n`` real. Their keys and values go into the
+    cache; a filler position's never do."""
+    t = len(pos)
+    b = x.shape[0] // t
     h, kv, dh = spec.n_heads, spec.n_kv_heads, spec.head_dim
+    g = h // kv
     pre = f"model.layers.{li}."
     xn = _rms_norm(x, provider.vector(pre + "input_layernorm.weight"),
                    spec.norm_eps)
-    q = provider.matmul(xn, pre + "self_attn.q_proj.weight").reshape(b, h, dh)
-    k = provider.matmul(xn, pre + "self_attn.k_proj.weight").reshape(b, kv, dh)
-    v = provider.matmul(xn, pre + "self_attn.v_proj.weight").reshape(b, kv, dh)
-    q = _rope(q, pos, spec.rope_theta)
-    k = _rope(k, pos, spec.rope_theta)
-    kc[li][:, :, pos] = k
-    vc[li][:, :, pos] = v
-    # Grouped-query attention: g query heads share each KV head.
-    g = h // kv
-    qg = q.reshape(b, kv, g, dh)
-    keys = kc[li][:, :, :pos + 1]
-    vals = vc[li][:, :, :pos + 1]
-    s = np.einsum("bkgd,bktd->bkgt", qg, keys) / np.sqrt(dh)
-    o = np.einsum("bkgt,bktd->bkgd", _softmax(s), vals).reshape(b, h * dh)
+    q = provider.matmul(xn, pre + "self_attn.q_proj.weight").reshape(
+        b, t, kv, g, dh)
+    k = provider.matmul(xn, pre + "self_attn.k_proj.weight").reshape(b, t, kv, dh)
+    v = provider.matmul(xn, pre + "self_attn.v_proj.weight").reshape(b, t, kv, dh)
+    end = pos[0] + n
+    kc[li][:, :, pos[0]:end] = _rope(k, pos, spec.rope_theta)[:, :n].swapaxes(1, 2)
+    vc[li][:, :, pos[0]:end] = v[:, :n].swapaxes(1, 2)
+    # Grouped-query attention: the g query heads of each KV head, at
+    # every position of the run, are the rows of one gemm.
+    q = _rope(q, pos, spec.rope_theta).transpose(0, 2, 3, 1, 4).reshape(
+        b, kv, g * t, dh)
+    s = (q @ kc[li][:, :, :end].swapaxes(-1, -2)) / np.sqrt(dh)
+    s = s.reshape(b, kv, g, t, end)
+    o = _attend(s, vc[li][:, :, None, :end], pos)  # (b, kv, g, t, dh)
+    o = o.transpose(0, 3, 1, 2, 4).reshape(b * t, h * dh)
     return provider.matmul(o, pre + "self_attn.o_proj.weight")
 
 
@@ -294,39 +332,44 @@ def _mlp_block(provider, li: int, x: np.ndarray, spec) -> np.ndarray:
     return _swiglu(provider, xn, pre + "mlp.")
 
 
-def _mla_block(provider, li: int, x: np.ndarray, cache: dict, pos: int,
-               spec: DeepseekV3Spec) -> np.ndarray:
-    """Latent attention at one position. The cache is held expanded, as
-    HF's ``deepseek_v3`` holds it: the new token's normed latent is
-    up-projected through ``kv_b_proj``, and its per-head no-rope keys
-    and values are cached beside the one rope key all heads share."""
-    b, h = x.shape[0], spec.n_heads
+def _mla_block(provider, li: int, x: np.ndarray, cache: dict,
+               pos: np.ndarray, n: int, spec: DeepseekV3Spec) -> np.ndarray:
+    """Latent attention over a run: ``x`` (b*T, d) holds each sequence's
+    T positions ``pos``, the first ``n`` real. The cache is held
+    expanded, as HF's ``deepseek_v3`` holds it: a real position's normed
+    latent is up-projected through ``kv_b_proj``, and its per-head
+    no-rope keys and values are cached beside the one rope key all heads
+    share; a filler position's never are."""
+    t = len(pos)
+    b, h = x.shape[0] // t, spec.n_heads
     nope, rope, dv = (spec.qk_nope_head_dim, spec.qk_rope_head_dim,
                       spec.v_head_dim)
+    lora = spec.kv_lora_rank
     pre = f"model.layers.{li}."
     att = pre + "self_attn."
     with trace("mla"):
         xn = _rms_norm(x, provider.vector(pre + "input_layernorm.weight"),
                        spec.norm_eps)
-        q = provider.matmul(xn, att + "q_proj.weight").reshape(b, h, nope + rope)
+        q = provider.matmul(xn, att + "q_proj.weight").reshape(b, t, h, nope + rope)
         kv_a = provider.matmul(xn, att + "kv_a_proj_with_mqa.weight")
-        latent = _rms_norm(kv_a[:, :spec.kv_lora_rank],
+        latent = _rms_norm(kv_a[:, :lora],
                            provider.vector(att + "kv_a_layernorm.weight"),
                            spec.norm_eps)
         kv = provider.matmul(latent, att + "kv_b_proj.weight").reshape(
-            b, h, nope + dv)
+            b, t, h, nope + dv)[:, :n].swapaxes(1, 2)
         k_nope, k_rope, v = cache["k_nope"][li], cache["k_rope"][li], cache["v"][li]
-        k_nope[:, :, pos] = kv[..., :nope]
-        v[:, :, pos] = kv[..., nope:]
-        k_rope[:, pos] = _rope(kv_a[:, spec.kv_lora_rank:], pos, spec.rope_theta)
-        q_rope = _rope(q[..., nope:], pos, spec.rope_theta)
-        # (b, h, 1, d) @ (b, h, d, t): one small gemm per sequence and head.
-        s = (q[..., None, :nope] @ k_nope[:, :, :pos + 1].swapaxes(-1, -2)
-             )[:, :, 0]
-        s += q_rope @ k_rope[:, :pos + 1].swapaxes(-1, -2)
+        end = pos[0] + n
+        k_nope[:, :, pos[0]:end] = kv[..., :nope]
+        v[:, :, pos[0]:end] = kv[..., nope:]
+        k_rope[:, pos[0]:end] = _rope(kv_a[:, lora:].reshape(b, t, rope), pos,
+                                      spec.rope_theta)[:, :n]
+        q_rope = _rope(q[..., nope:], pos, spec.rope_theta).swapaxes(1, 2)
+        # (b, h, T, d) @ (b, h, d, S): one small gemm per sequence and head.
+        s = q[..., :nope].swapaxes(1, 2) @ k_nope[:, :, :end].swapaxes(-1, -2)
+        s += q_rope @ k_rope[:, None, :end].swapaxes(-1, -2)
         s /= np.sqrt(nope + rope)
-        o = (_softmax(s)[:, :, None] @ v[:, :, :pos + 1])[:, :, 0]
-        return provider.matmul(o.reshape(b, h * dv), att + "o_proj.weight")
+        o = _attend(s, v[:, :, :end], pos).swapaxes(1, 2)  # (b, T, h, dv)
+        return provider.matmul(o.reshape(b * t, h * dv), att + "o_proj.weight")
 
 
 def _route(provider, pre: str, xn: np.ndarray, spec: DeepseekV3Spec):
@@ -345,11 +388,13 @@ def _route(provider, pre: str, xn: np.ndarray, spec: DeepseekV3Spec):
 
 
 def _moe_block(provider, li: int, x: np.ndarray, spec: DeepseekV3Spec,
-               routing: list | None) -> np.ndarray:
+               routing: list | None, live: np.ndarray | None = None) -> np.ndarray:
     """One MoE layer's share: the router over every expert, this model's
     held experts' part of the routed result, and the shared experts.
     Each held expert runs over the whole batch, in one grouped seam call
-    a projection; rows not routed to it are combined with weight 0."""
+    a projection; rows not routed to it are combined with weight 0.
+    ``live`` (M,), where given, marks the rows of real positions: the
+    rest are routed to no expert and counted in no assignment."""
     pre = f"model.layers.{li}."
     xn = _rms_norm(x, provider.vector(pre + "post_attention_layernorm.weight"),
                    spec.norm_eps)
@@ -357,10 +402,13 @@ def _moe_block(provider, li: int, x: np.ndarray, spec: DeepseekV3Spec,
     with trace("route"):
         ids, w, biased = _route(provider, pre, xn, spec)
         hit = ids[..., None] == held  # (b, top_k, held)
+        if live is not None:
+            hit &= live[:, None, None]
         combine = np.einsum("bk,bke->be", w, hit).astype(np.float32)
         n_held = int(hit.sum())
+        n_live = len(ids) if live is None else int(live.sum())
         MOE_ASSIGNMENTS.labels("held").inc(n_held)
-        MOE_ASSIGNMENTS.labels("absent").inc(ids.size - n_held)
+        MOE_ASSIGNMENTS.labels("absent").inc(n_live * spec.top_k - n_held)
     if routing is not None:
         routing.append((ids, biased))
     names = [f"{pre}mlp.experts.{e}." for e in spec.held_experts]
@@ -377,20 +425,22 @@ def _moe_block(provider, li: int, x: np.ndarray, spec: DeepseekV3Spec,
     return routed + _swiglu(provider, xn, pre + "mlp.shared_experts.")
 
 
-def _llama_layers(provider, x, cache, pos, spec, routing):
+def _llama_layers(provider, x, cache, pos, n, spec, routing):
     for li in range(spec.n_layers):
-        x = x + _attn_block(provider, li, x, cache["k"], cache["v"], pos, spec)
+        x = x + _attn_block(provider, li, x, cache["k"], cache["v"], pos, n,
+                            spec)
         x = x + _mlp_block(provider, li, x, spec)
     return x
 
 
-def _deepseek_layers(provider, x, cache, pos, spec, routing):
+def _deepseek_layers(provider, x, cache, pos, n, spec, routing):
+    live = np.tile(np.arange(len(pos)) < n, x.shape[0] // len(pos))
     for li in range(spec.n_layers):
-        x = x + _mla_block(provider, li, x, cache, pos, spec)
+        x = x + _mla_block(provider, li, x, cache, pos, n, spec)
         if li < spec.first_k_dense:
             x = x + _mlp_block(provider, li, x, spec)
         else:
-            x = x + _moe_block(provider, li, x, spec, routing)
+            x = x + _moe_block(provider, li, x, spec, routing, live)
     return x
 
 
@@ -426,12 +476,25 @@ def greedy_decode(provider, spec, prompt: np.ndarray, steps: int,
     top_k), "scores": (L_moe, B, P - 1 + steps, n_experts)}``, the
     chosen experts and the biased scores they were chosen on.
 
+    One forward consumes a run of positions of every sequence, B x T
+    rows, attending causally over the cache and the run. The prompt runs
+    in chunks of ``C = max(1, PREFILL_ROWS // B)`` positions, the last
+    padded at its end with filler positions that are never cached,
+    routed or returned; then each generated token but the last is one
+    forward of one position. The LM head runs only where a token is
+    chosen: on the last prompt position and on each generated token.
+
     The call is one ``generate`` span (``batch``, ``prompt``, ``steps``)
-    with a ``forward`` child per position: ``P - 1 + steps`` of them.
-    A forward's self time is the host math (embedding gather, norms,
-    rope, attention, argmax); its matmuls are the seam's spans. Under a
+    with a ``forward`` child per chunk and per generated token but the
+    last: ``ceil(P / C) + steps - 1`` of them, each with its real
+    ``positions`` and ``padded`` filler positions a sequence. A forward's
+    self time is the host math (embedding gather, norms, rope,
+    attention, argmax); its matmuls are the seam's spans. Under a
     :class:`DeepseekV3Spec` a forward holds an ``mla`` span a layer and
     a ``route`` and an ``experts`` span a MoE layer.
+    ``neurstore_decode_positions_total`` counts the positions consumed,
+    summed over the sequences, by phase: ``prefill`` (the prompt's),
+    ``decode`` (generated tokens fed back) and ``padding`` (filler).
     """
     moe = isinstance(spec, DeepseekV3Spec)
     if return_routing and not moe:
@@ -439,40 +502,52 @@ def greedy_decode(provider, spec, prompt: np.ndarray, steps: int,
     layers = _deepseek_layers if moe else _llama_layers
     prompt = np.atleast_2d(np.asarray(prompt, dtype=np.int64))
     b, p = prompt.shape
+    chunk = max(1, PREFILL_ROWS // b)
     cache = _new_cache(spec, b, p + steps)
     generated: list[np.ndarray] = []
     logits_trace: list[np.ndarray] = []
     routing_trace: list[list] = []
-    tok = prompt[:, 0]
-    pos = 0
+    start = 0
     with trace("generate", batch=b, prompt=p, steps=steps):
         while len(generated) < steps:
-            routing = [] if return_routing else None
-            with trace("forward"):
-                x = provider.gather_rows("model.embed_tokens.weight", tok)
-                x = layers(provider, x, cache, pos, spec, routing)
-                x = _rms_norm(x, provider.vector("model.norm.weight"),
-                              spec.norm_eps)
-                logits = provider.matmul(x, "lm_head.weight")
-                nxt = np.argmax(logits, axis=1)
-            if return_routing:
-                routing_trace.append(routing)
-            pos += 1
-            if pos < p:
-                tok = prompt[:, pos]
+            if start < p:
+                n = min(chunk, p - start)
+                ids = np.pad(prompt[:, start:start + n], ((0, 0), (0, chunk - n)))
+                DECODE_POSITIONS.labels("prefill").inc(b * n)
+                DECODE_POSITIONS.labels("padding").inc(b * (chunk - n))
             else:
-                tok = nxt
-                generated.append(nxt)
+                n, ids = 1, tok[:, None]
+                DECODE_POSITIONS.labels("decode").inc(b)
+            t = ids.shape[1]
+            chooses = start + n >= p
+            routing = [] if return_routing else None
+            with trace("forward", positions=n, padded=t - n):
+                x = provider.gather_rows("model.embed_tokens.weight",
+                                         ids.reshape(-1))
+                x = layers(provider, x, cache, start + np.arange(t), n, spec,
+                           routing)
+                if chooses:
+                    x = _rms_norm(x.reshape(b, t, -1)[:, n - 1],
+                                  provider.vector("model.norm.weight"),
+                                  spec.norm_eps)
+                    logits = provider.matmul(x, "lm_head.weight")
+                    tok = np.argmax(logits, axis=1)
+            if return_routing:
+                routing_trace.append([[a.reshape(b, t, -1)[:, :n] for a in layer]
+                                      for layer in routing])
+            start += n
+            if chooses:
+                generated.append(tok)
                 if return_logits:
                     logits_trace.append(logits)
     out = [np.stack(generated, axis=1)]
     if return_logits:
         out.append(np.stack(logits_trace, axis=1))
     if return_routing:
-        # routing_trace[position][layer] = (ids (B, k), scores (B, E)).
-        out.append({key: np.stack([np.stack([layer[j] for layer in step])
-                                   for step in routing_trace], axis=2)
-                    for j, key in enumerate(("ids", "scores"))})
+        # routing_trace[forward][layer] = (ids (B, n, k), scores (B, n, E)).
+        out.append({key: np.concatenate(
+            [np.stack([layer[j] for layer in fwd]) for fwd in routing_trace],
+            axis=2) for j, key in enumerate(("ids", "scores"))})
     return out[0] if len(out) == 1 else tuple(out)
 
 

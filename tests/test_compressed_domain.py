@@ -80,10 +80,11 @@ def test_compressed_decode_matches_materialized_zero_materialize(
     np.testing.assert_array_equal(tokens, want_tokens)
     np.testing.assert_allclose(logits, want_logits, rtol=1e-4, atol=1e-4)
     # Each matmul of the request is a seam span under its generate root:
-    # 7 a layer plus the LM head, each forward.
+    # 7 a layer plus the LM head, each forward (the prompt is one chunk,
+    # then one forward a generated token but the last).
     assert gen.name == "generate"
     seam = [s for s in gen.walk() if s.name == "dequant_matmul"]
-    forwards = PROMPT.shape[1] - 1 + 6
+    forwards = 1 + 6 - 1
     assert len(seam) == forwards * (7 * SPEC.n_layers + 1)
     assert all(s.attrs["operand_bytes"] > 0 for s in seam)
     lm.close()

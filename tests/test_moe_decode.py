@@ -5,7 +5,9 @@ through one grouped seam call each (``kernels.ops.dequant_matmul_group``).
 The grouped seam against per-weight ``dequant_matmul_auto`` calls, on the
 interpret and host routes; the kernel route's residency; rows a layer
 routes to no held expert; the span tree and the assignment counter of a
-decode off the store; and the spec's catalog payload.
+decode off the store; a prompt consumed in chunks against one consumed a
+position a forward, for both block families, and the chunks' filler
+positions counted nowhere; and the spec's catalog payload.
 """
 
 import numpy as np
@@ -219,10 +221,11 @@ def test_rows_routed_to_no_held_expert_get_exactly_the_shared_experts():
 
 
 def test_moe_decode_spans_and_assignment_counter(moe_engine):
-    """``forward`` > ``mla`` a layer (its seam calls under it), ``route``
-    and ``experts`` a MoE layer; the router's matmul under ``route``,
-    three grouped calls under ``experts``. Every token's top-k choice is
-    counted as held or absent."""
+    """One ``forward`` a prompt chunk and a later token, > ``mla`` a layer
+    (its seam calls under it), ``route`` and ``experts`` a MoE layer; the
+    router's matmul under ``route``, three grouped calls under
+    ``experts``. Every token's top-k choice is counted as held or
+    absent."""
     reg = default_registry()
 
     def count(placement):
@@ -240,7 +243,12 @@ def test_moe_decode_spans_and_assignment_counter(moe_engine):
     assert gen.name == "generate"
     forwards = gen.children
     n_moe = SPEC.n_layers - SPEC.first_k_dense
-    assert len(forwards) == 3 - 1 + 2
+    # The 3-position prompt is one chunk, then one forward for the
+    # second token.
+    assert len(forwards) == 1 + 2 - 1
+    assert [f.attrs for f in forwards] == [
+        {"positions": 3, "padded": cs.PREFILL_ROWS // 2 - 3},
+        {"positions": 1, "padded": 0}]
     for f in forwards:
         names = [c.name for c in f.children]
         assert names.count("mla") == SPEC.n_layers
@@ -257,11 +265,108 @@ def test_moe_decode_spans_and_assignment_counter(moe_engine):
                            and len(s.attrs["routed_rows"]) == s.attrs["experts"]
                            for s in c.children)
     ids = routing["ids"]
-    assert ids.shape == (n_moe, 2, len(forwards), SPEC.top_k)
-    assert routing["scores"].shape == (n_moe, 2, len(forwards), SPEC.n_experts)
+    assert ids.shape == (n_moe, 2, 3 - 1 + 2, SPEC.top_k)
+    assert routing["scores"].shape == (n_moe, 2, 3 - 1 + 2, SPEC.n_experts)
     held = int(np.isin(ids, SPEC.held_experts).sum())
     assert count("held") - before["held"] == held
     assert count("absent") - before["absent"] == ids.size - held
+
+
+LLAMA = cs.DecoderSpec(d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
+                      n_layers=2, vocab_size=96)
+CHUNK = 4
+
+
+@pytest.fixture(scope="module")
+def two_decoders(tmp_path_factory):
+    """A stored decoder of each block family, under its spec's kind."""
+    eng = StorageEngine(tmp_path_factory.mktemp("decoders"))
+    cs.save_decoder(eng, LLAMA.kind, LLAMA, seed=3)
+    eng.save_model(SPEC.kind, cs.decoder_architecture(SPEC),
+                   deepseek_tensors(SPEC, seed=4))
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("p", [1, CHUNK - 1, CHUNK, CHUNK + 1, 2 * CHUNK + 1])
+@pytest.mark.parametrize("source", ["compressed", "materialized"])
+@pytest.mark.parametrize("spec", [LLAMA, SPEC], ids=["gqa", "mla_moe"])
+def test_chunked_prefill_equals_one_position_prefill(two_decoders, spec,
+                                                     source, p, monkeypatch):
+    """A prompt consumed in chunks of CHUNK positions (the last padded)
+    gives the tokens, logits and routing of the same prompt consumed one
+    position a forward, at every prompt length about the chunk's."""
+    lm = two_decoders.load_model(spec.kind, bits=8)
+    provider = (CompressedModel(lm, force="numpy") if source == "compressed"
+                else cs.MaterializedProvider(lm))
+    prompt = np.random.default_rng(p).integers(0, spec.vocab_size, (2, p))
+    moe = spec is SPEC
+
+    def decode(rows):
+        monkeypatch.setattr(cs, "PREFILL_ROWS", rows)
+        return cs.greedy_decode(provider, spec, prompt, 3, return_logits=True,
+                                return_routing=moe)
+
+    chunked, single = decode(2 * CHUNK), decode(1)
+    provider.close()
+    np.testing.assert_array_equal(chunked[0], single[0])
+    np.testing.assert_allclose(chunked[1], single[1], rtol=0, atol=1e-5)
+    if moe:
+        n_moe = SPEC.n_layers - SPEC.first_k_dense
+        for key, width in (("ids", SPEC.top_k), ("scores", SPEC.n_experts)):
+            assert chunked[2][key].shape == single[2][key].shape == (
+                n_moe, 2, p - 1 + 3, width)
+        np.testing.assert_array_equal(chunked[2]["ids"], single[2]["ids"])
+        # Scores of order 1 in float32: a few ulps of reassociation.
+        np.testing.assert_allclose(chunked[2]["scores"], single[2]["scores"],
+                                   rtol=0, atol=1e-6)
+
+
+def test_chunk_filler_is_neither_routed_nor_counted(two_decoders, monkeypatch):
+    """The filler that pads a prompt's last chunk reaches no assignment
+    count and no expert's ``routed_rows``; the position counter splits a
+    request into its prompt, its fed-back tokens and the filler."""
+    reg = default_registry()
+
+    def count(name, labels):
+        return reg.sample_value(name, labels) or 0
+
+    def counts():
+        return ({ph: count("neurstore_decode_positions_total", {"phase": ph})
+                 for ph in ("prefill", "decode", "padding")},
+                {pl: count("neurstore_moe_assignments_total", {"placement": pl})
+                 for pl in ("held", "absent")})
+
+    monkeypatch.setattr(cs, "PREFILL_ROWS", 2 * CHUNK)
+    b, p, steps = 2, CHUNK + 1, 3
+    prompt = np.arange(b * p).reshape(b, p) % SPEC.vocab_size
+    lm = two_decoders.load_model(SPEC.kind, bits=8)
+    model = CompressedModel(lm)
+    positions, assignments = counts()
+    _, routing = cs.greedy_decode(model, SPEC, prompt, steps,
+                                  return_routing=True)
+    gen = recent_traces()[-1]
+    model.close()
+    after_positions, after_assignments = counts()
+    assert {ph: after_positions[ph] - positions[ph] for ph in positions} == {
+        "prefill": b * p, "decode": b * (steps - 1),
+        "padding": b * (-(-p // CHUNK) * CHUNK - p)}
+    ids = routing["ids"]  # (L_moe, b, p - 1 + steps, top_k): real positions
+    held = int(np.isin(ids, SPEC.held_experts).sum())
+    assert after_assignments["held"] - assignments["held"] == held
+    assert after_assignments["absent"] - assignments["absent"] == \
+        ids.size - held
+    # The second chunk holds position CHUNK and CHUNK - 1 filler positions
+    # a sequence: its experts see only position CHUNK's rows routed.
+    padded = gen.children[1]
+    assert padded.attrs == {"positions": 1, "padded": CHUNK - 1}
+    layers = [c for c in padded.children if c.name == "experts"]
+    assert len(layers) == ids.shape[0]
+    for layer, chosen in zip(layers, ids[:, :, CHUNK]):
+        want = [int((chosen == e).any(axis=1).sum()) for e in SPEC.held_experts]
+        for call in layer.children:
+            assert call.attrs["m"] == b * CHUNK
+            assert call.attrs["routed_rows"] == want
 
 
 def test_return_routing_needs_moe_layers():

@@ -452,6 +452,7 @@ def test_quantized_l2_span_carries_route_and_shape(route, monkeypatch):
 def test_generate_nests_forwards_and_seam_calls(tmp_path):
     from repro.core import CompressedModel
     from repro.launch.compressed_serve import (
+        PREFILL_ROWS,
         DecoderSpec,
         greedy_decode,
         save_decoder,
@@ -468,12 +469,18 @@ def test_generate_nests_forwards_and_seam_calls(tmp_path):
     eng.close()
     assert gen.name == "generate"
     assert gen.attrs == {"batch": 2, "prompt": 3, "steps": 4}
-    assert [c.name for c in gen.children] == ["forward"] * (3 - 1 + 4)
-    for fwd in gen.children:
+    # The prompt is one chunk of C positions a sequence, then one
+    # forward a generated token but the last.
+    chunk = PREFILL_ROWS // 2
+    assert [c.name for c in gen.children] == ["forward"] * (1 + 4 - 1)
+    for i, fwd in enumerate(gen.children):
         assert [c.name for c in fwd.children] == \
             ["dequant_matmul"] * (7 * spec.n_layers + 1)
-        assert all(c.attrs["route"] == "host" and c.attrs["m"] == 2
-                   for c in fwd.children)
+        assert all(c.attrs["route"] == "host" for c in fwd.children)
+        # Prefill matmuls carry 2 x C rows; decode matmuls and the
+        # prefill's LM head (its last prompt position) 2.
+        assert [c.attrs["m"] for c in fwd.children] == (
+            [2 * chunk if i == 0 else 2] * (7 * spec.n_layers) + [2])
 
 
 # ------------------------------------------- propagation through the server
