@@ -29,12 +29,14 @@ Durability model
 
 Record shapes (all JSON, one object per line; see ``docs/lifecycle.md``):
 
-* ``{"tx", "op": "save",    "name", "id", "page", "new_vertices"}``
-* ``{"tx", "op": "delete",  "name", "id", "page", "refs"}``
-* ``{"tx", "op": "replace", "name", "id", "page", "new_vertices",
-  "old_id", "old_page", "old_refs"}``
 * ``{"tx", "op": "save_batch", "models": [{"name", "id", "page"[,
-  "old_id", "old_page", "old_refs"]}, …], "new_vertices"}``
+  "old_id", "old_page", "old_refs"]}, …], "new_vertices"}`` — every save
+  (``save_model`` is the batch of one); a member with ``old_*`` is a replace
+* ``{"tx", "op": "delete",  "name", "id", "page", "refs"}``
+* ``{"tx", "op": "save",    "name", "id", "page", "new_vertices"}`` and
+  ``{"tx", "op": "replace", "name", "id", "page", "new_vertices",
+  "old_id", "old_page", "old_refs"}`` — read only to replay journals of
+  earlier versions, as the one-member ``save_batch`` they describe
 * ``{"tx", "op": "vacuum",        "dim", "pages"}``
 * ``{"tx", "op": "vacuum_switch", "dim", "index", "pages", "refs"}``
 * ``{"tx", "op": "commit"}``
@@ -43,7 +45,7 @@ Record shapes (all JSON, one object per line; see ``docs/lifecycle.md``):
 the model held); ``new_vertices`` is ``[[dim, vertex_id], …]`` (vertices
 first created by the interrupted save). ``vacuum_switch.refs`` is the full
 post-remap ``{vertex_id: count}`` map for the dim, recorded wholesale so
-roll-forward replay is idempotent. ``save_batch`` (``save_models``) commits
+roll-forward replay is idempotent. ``save_batch`` commits
 every listed model through ONE snapshot replace — replay is all-or-nothing
 across the batch, keyed off the first member's presence in the snapshot.
 

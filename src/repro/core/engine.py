@@ -813,23 +813,17 @@ class StorageEngine:
         return changed
 
     def _recover_put(self, rec: dict) -> None:
-        entry = self.catalog.get(rec["name"])
-        if entry is not None and entry.model_id == rec["id"]:
-            # Snapshot switched before the crash: the save committed. For a
-            # replace, finish dropping the old version's remains.
-            if rec["op"] == "replace":
-                old_refs = [(int(d), int(v)) for d, v, _c in rec.get("old_refs", [])]
-                self._tombstone_unreferenced(old_refs)
-                if rec.get("old_page"):
-                    self._unlink(self._page_file(rec["old_page"]))
-            return
-        # Snapshot never switched: undo the physical side effects.
-        self._unlink(self._page_file(rec["page"]))
-        new_pairs = [(int(d), int(v)) for d, v in rec.get("new_vertices", [])]
-        self._tombstone_unreferenced(new_pairs)
+        """Replay a ``save``/``replace`` intent — the one-model shape that
+        earlier versions journaled — as the one-model batch it is."""
+        model = {k: rec[k] for k in ("name", "id", "page", "old_id",
+                                      "old_page", "old_refs") if k in rec}
+        self._recover_save_batch({
+            "models": [model], "new_vertices": rec.get("new_vertices", []),
+        })
 
     def _recover_save_batch(self, rec: dict) -> None:
-        """Replay an interrupted ``save_models``: all-or-nothing.
+        """Replay an interrupted save (``save_model``/``save_models``):
+        all-or-nothing.
 
         The snapshot replace is the single commit point for every model in
         the batch, so checking any one member tells the whole story: if its
@@ -1082,221 +1076,13 @@ class StorageEngine:
 
         Saving under an existing name is a **replace**: the new version is
         written first, then the old page and its vertex references are
-        dropped, all under one journal transaction.
+        dropped, all under one journal transaction. This is
+        :meth:`save_models` of one model, under its own span (``engine.save``),
+        metric label and failpoints (``save.after_intent`` / …).
         """
-        with trace("engine.save", model=name) as op:
-            report = self._save_model_impl(
-                name, architecture, tensors, tolerance, tau, op
-            )
-        _M_OPS.labels("save").inc()
-        _M_OP_SECONDS.labels("save").observe(op.elapsed())
-        return report
-
-    def _save_model_impl(
-        self,
-        name: str,
-        architecture: dict,
-        tensors,
-        tolerance: float | None,
-        tau: float | None,
-        op,
-    ) -> SaveReport:
-        # `op` is the open engine.save span: SaveReport.seconds is derived
-        # from it, so wall time in the report and the trace cannot differ.
-        self._check_writable()
-        self._drain_released()
-        p = self.tolerance if tolerance is None else tolerance
-        tau_ = self.tau if tau is None else tau
-        # Grouping needs only names/shapes — no float64 upcast is made here.
-        items: list[tuple[str, tuple[int, ...], object]] = []
-        by_dim: "OrderedDict[int, list[int]]" = OrderedDict()
-        original_bytes = 0
-        for tname, tensor in tensors.items():
-            src = np.asarray(tensor)
-            original_bytes += src.size * 4  # stored models are float32
-            by_dim.setdefault(src.size, []).append(len(items))
-            items.append((tname, tuple(int(s) for s in src.shape), src))
-
-        # Phase 1 (locked): per-dim batched ANN probe / batch vertex insert
-        # (Alg. 1 l.2-3 through `_probe_dim_group`): one distance block +
-        # one quantization sweep + one `insert_batch` per dim instead of
-        # per-tensor graph probes. Dims are pinned so a concurrent load's
-        # cache fetch cannot evict an index this save is mutating. The
-        # float64 upcast now lives per *group* (the batch paths need the
-        # (G, dim) block), released as each group resolves.
-        bases: list[tuple[int, np.ndarray] | None] = [None] * len(items)
-        probe_ex: list[dict | None] = [None] * len(items)
-        refs: Counter = Counter()
-        new_vertices: list[tuple[int, int]] = []
-        n_new = 0
-        try:
-            for dim in by_dim:
-                self.index_cache.pin(dim)
-            try:
-                with trace("probe", n_dims=len(by_dim)), self._lock:
-                    for dim, positions in by_dim.items():
-                        self._check_quarantine(dim)
-                        index = self.index_cache.get(dim, create=True)
-                        for chunk in self._iter_group_chunks(positions, dim):
-                            flats = self._stack_flats(
-                                [items[pos][2] for pos in chunk], dim)
-                            group_bases, group_new, group_ex = (
-                                self._probe_dim_group(index, flats, tau_)
-                            )
-                            if group_new:
-                                self.index_cache.mark_dirty(dim)
-                                new_vertices.extend(
-                                    (dim, v) for v in group_new
-                                )
-                                n_new += len(group_new)
-                            for gj, pos in enumerate(chunk):
-                                vid, delta = group_bases[gj]
-                                bases[pos] = (vid, delta)
-                                probe_ex[pos] = group_ex[gj]
-                                refs[(dim, vid)] += 1
-                                # Hold the ref until commit so a concurrent
-                                # delete cannot tombstone this base under
-                                # the page.
-                                self._inflight[(dim, vid)] += 1
-            finally:
-                for dim in by_dim:
-                    self.index_cache.unpin(dim)
-
-            # Phase 2 (unlocked): adaptive n-bit quantization of each delta
-            # (Eq. 2/3) + planar bit-packing + page assembly, in tensor
-            # order. Deltas are released as they are consumed.
-            records: list[TensorRecord] = []
-            nbits: list[int] = []
-            explain: list[dict] = []
-            with trace("quantize", n_tensors=len(items)):
-                for i, (tname, shape, src) in enumerate(items):
-                    vid, delta = bases[i]
-                    bases[i] = None
-                    qd, meta = quantize_delta(delta, p)
-                    nbits.append(meta.nbit)
-                    rec = TensorRecord(
-                        name=tname,
-                        shape=shape,
-                        dim_key=src.size,
-                        vertex_id=vid,
-                        meta=meta,
-                        qdelta=qd,
-                    )
-                    with trace("encode"):
-                        rec.payload = encode_payload(rec)
-                    records.append(rec)
-                    ex = probe_ex[i]
-                    explain.append({
-                        "tensor": tname,
-                        "dim": int(src.size),
-                        "vertex_id": int(ex["vertex_id"]),
-                        "outcome": ex["outcome"],
-                        "probe_distance": ex["probe_distance"],
-                        "delta_range": ex["delta_range"],
-                        "tau": float(tau_),
-                        "nbit": int(meta.nbit),
-                        "delta_bytes": len(rec.payload),
-                        "error_bound": float(p),
-                    })
-            with trace("pack"):
-                page = write_page(records, checksums=self.checksums)
-
-            # Phase 3 (locked): the journaled commit. Intent → index flush
-            # (vertices durable before the page references them) → page
-            # write → atomic catalog snapshot (commit point) → old-version
-            # cleanup → journal commit. The span opens before the lock so
-            # lock-wait time is attributed to the commit.
-            with trace("commit"), self._lock:
-                old = self.catalog.get(name)
-                old_refs = self._page_refs(old.page) if old else Counter()
-                if self.commit_gate is not None:
-                    self.commit_gate([{
-                        "name": name,
-                        "page_bytes": len(page),
-                        "old_page_bytes": self._page_size(old),
-                    }])
-                model_id = self.catalog.allocate_id()
-                page_name = f"model_{model_id}.page"
-                intent = {
-                    "op": "replace" if old else "save",
-                    "name": name,
-                    "id": model_id,
-                    "page": page_name,
-                    "new_vertices": [[d, v] for d, v in new_vertices],
-                }
-                if old:
-                    intent["old_id"] = old.model_id
-                    intent["old_page"] = old.page
-                    intent["old_refs"] = [
-                        [d, v, c] for (d, v), c in old_refs.items()
-                    ]
-                with trace("journal"):
-                    tx = self.catalog.begin(intent)
-                maybe_fail("save.after_intent")
-                self.index_cache.flush()
-                maybe_fail("save.after_index_flush")
-                self.fs.write_durable(
-                    self._page_file(page_name), page, site="page.write"
-                )
-                maybe_fail("save.after_page_write")
-                entry = ModelEntry(
-                    model_id=model_id,
-                    name=name,
-                    architecture=architecture,
-                    page=page_name,
-                    n_tensors=len(records),
-                    original_bytes=original_bytes,
-                    status=STATUS_PENDING,
-                    explain=(explain[:EXPLAIN_PERSIST_MAX]
-                             if self.accounting else None),
-                )
-                self.catalog.state.models[name] = entry
-                for (dim, vid), c in refs.items():
-                    self.catalog.ref(dim, vid, c)
-                if old:
-                    for (dim, vid), c in old_refs.items():
-                        self.catalog.ref(dim, vid, -c)
-                entry.status = STATUS_COMMITTED
-                self.catalog.save_snapshot()  # ← commit point
-                self._account_committed_save(
-                    name, model_id, page_name, len(page), original_bytes,
-                    tuple(
-                        TensorSpace(rec.dim_key, rec.vertex_id, rec.numel,
-                                    len(rec.payload))
-                        for rec in records
-                    ),
-                    explain,
-                )
-                maybe_fail("save.after_snapshot")
-                if old:
-                    self._tombstone_unreferenced(old_refs)
-                    self.index_cache.flush()
-                    self._unlink(self._page_file(old.page))
-                    self._pending_explains.pop(old.model_id, None)
-                    self._unlink(self._explain_file(old.model_id))
-                    self.page_pool.invalidate(old.page)
-                self.catalog.commit_tx(tx)
-                self.index_cache.trim()
-        finally:
-            with self._lock:
-                for pair, c in refs.items():
-                    left = self._inflight[pair] - c
-                    if left > 0:
-                        self._inflight[pair] = left
-                    else:
-                        del self._inflight[pair]
-        return SaveReport(
-            model_id=model_id,
-            name=name,
-            original_bytes=original_bytes,
-            page_bytes=len(page),
-            n_tensors=len(records),
-            n_new_bases=n_new,
-            n_deltas=len(records) - n_new,
-            nbits=nbits,
-            seconds=op.elapsed(),
-            explain=explain,
-        )
+        return self._save(
+            "save", [(name, architecture, tensors)], tolerance, tau, model=name
+        )[0]
 
     def save_models(
         self,
@@ -1325,14 +1111,26 @@ class StorageEngine:
         Returns one :class:`SaveReport` per model, in input order, with the
         batch wall time amortized evenly over the ``seconds`` fields.
         """
-        with trace("engine.save_batch") as op:
-            reports = self._save_models_impl(models, tolerance, tau, op)
-        _M_OPS.labels("save_batch").inc()
-        _M_OP_SECONDS.labels("save_batch").observe(op.elapsed())
+        return self._save("save_batch", models, tolerance, tau)
+
+    def _save(self, label: str, models, tolerance, tau,
+              **attrs) -> list[SaveReport]:
+        """The one save transaction. ``label`` names its root span
+        (``engine.<label>``), its op metric label and its failpoint
+        prefix."""
+        with trace(f"engine.{label}", **attrs) as op:
+            reports = self._save_models_impl(label, models, tolerance, tau, op)
+        _M_OPS.labels(label).inc()
+        _M_OP_SECONDS.labels(label).observe(op.elapsed())
         return reports
 
-    def _save_models_impl(self, models, tolerance, tau, op) -> list[SaveReport]:
+    def _save_models_impl(
+        self, label: str, models, tolerance, tau, op
+    ) -> list[SaveReport]:
+        # `op` is the open root span: SaveReport.seconds is derived from
+        # it, so wall time in the report and the trace cannot differ.
         self._check_writable()
+        self._drain_released()
         p = self.tolerance if tolerance is None else tolerance
         tau_ = self.tau if tau is None else tau
         specs = [(str(n), a, t) for n, a, t in models]
@@ -1343,6 +1141,7 @@ class StorageEngine:
             return []
 
         # Flatten: per-model item lists + one cross-model dim grouping.
+        # Grouping needs only names/shapes — no float64 upcast is made here.
         all_items: list[list[tuple[str, tuple[int, ...], object]]] = []
         original_bytes: list[int] = []
         by_dim: "OrderedDict[int, list[tuple[int, int]]]" = OrderedDict()
@@ -1357,8 +1156,13 @@ class StorageEngine:
             all_items.append(items)
             original_bytes.append(nbytes)
 
-        # Phase 1 (locked): one batched probe + insert per dim for the
-        # whole batch — the cross-model half of the ingest amortization.
+        # Phase 1 (locked): per-dim batched ANN probe / batch vertex insert
+        # (Alg. 1 l.2-3 through `_probe_dim_group`) for the whole batch:
+        # one distance block + one quantization sweep + one `insert_batch`
+        # per dim instead of per-tensor graph probes. Dims are pinned so a
+        # concurrent load's cache fetch cannot evict an index this save is
+        # mutating. The float64 upcast lives per probe chunk, released as
+        # each chunk resolves.
         bases: list[list] = [[None] * len(items) for items in all_items]
         probe_ex: list[list] = [[None] * len(items) for items in all_items]
         refs: Counter = Counter()
@@ -1384,12 +1188,17 @@ class StorageEngine:
                                 new_vertices.extend(
                                     (dim, v) for v in group_new
                                 )
+                            # A new base counts for the model of the first
+                            # tensor that references it.
                             group_new_set = set(group_new)
                             for gj, (mi, pos) in enumerate(chunk):
                                 vid, delta = group_bases[gj]
                                 bases[mi][pos] = (vid, delta)
                                 probe_ex[mi][pos] = group_ex[gj]
                                 refs[(dim, vid)] += 1
+                                # Hold the ref until commit so a concurrent
+                                # delete cannot tombstone this base under
+                                # the page.
                                 self._inflight[(dim, vid)] += 1
                                 if vid in group_new_set:
                                     group_new_set.discard(vid)
@@ -1398,19 +1207,22 @@ class StorageEngine:
                 for dim in by_dim:
                     self.index_cache.unpin(dim)
 
-            # Phase 2 (unlocked): encode every model's page.
+            # Phase 2 (unlocked), per model: adaptive n-bit quantization of
+            # each delta (Eq. 2/3) + planar bit-packing in tensor order
+            # (`quantize`), then page assembly (`pack`). Deltas are
+            # released as they are consumed.
             pages: list[bytes] = []
             nbits_per_model: list[list[int]] = []
             explain_per_model: list[list[dict]] = []
             spaces_per_model: list[tuple] = []
-            with trace("quantize", n_models=len(all_items)):
-                for mi, items in enumerate(all_items):
-                    records: list[TensorRecord] = []
-                    nbits: list[int] = []
-                    explain: list[dict] = []
+            for mi, items in enumerate(all_items):
+                records: list[TensorRecord] = []
+                nbits: list[int] = []
+                explain: list[dict] = []
+                with trace("quantize", n_tensors=len(items)):
                     for i, (tname, shape, src) in enumerate(items):
                         vid, delta = bases[mi][i]
-                        bases[mi][i] = (vid, None)  # release the delta
+                        bases[mi][i] = None  # release the delta
                         qd, meta = quantize_delta(delta, p)
                         nbits.append(meta.nbit)
                         rec = TensorRecord(
@@ -1437,19 +1249,22 @@ class StorageEngine:
                             "delta_bytes": len(rec.payload),
                             "error_bound": float(p),
                         })
-                    with trace("pack"):
-                        pages.append(
-                            write_page(records, checksums=self.checksums)
-                        )
-                    nbits_per_model.append(nbits)
-                    explain_per_model.append(explain)
-                    spaces_per_model.append(tuple(
-                        TensorSpace(rec.dim_key, rec.vertex_id, rec.numel,
-                                    len(rec.payload))
-                        for rec in records
-                    ))
+                with trace("pack"):
+                    pages.append(write_page(records, checksums=self.checksums))
+                nbits_per_model.append(nbits)
+                explain_per_model.append(explain)
+                spaces_per_model.append(tuple(
+                    TensorSpace(rec.dim_key, rec.vertex_id, rec.numel,
+                                len(rec.payload))
+                    for rec in records
+                ))
 
             # Phase 3 (locked): ONE journaled commit for the whole batch.
+            # Intent → index flush (vertices durable before the pages
+            # reference them) → page writes → atomic catalog snapshot
+            # (commit point) → old-version cleanup → journal commit. The
+            # span opens before the lock so lock-wait time is attributed to
+            # the commit.
             with trace("commit"), self._lock:
                 olds = [self.catalog.get(n) for n in names]
                 old_refs = [
@@ -1467,7 +1282,7 @@ class StorageEngine:
                 model_ids = [self.catalog.allocate_id() for _ in specs]
                 page_names = [f"model_{mid}.page" for mid in model_ids]
                 intent_models = []
-                for mi, (name, _arch, _t) in enumerate(specs):
+                for mi, name in enumerate(names):
                     m: dict = {
                         "name": name,
                         "id": model_ids[mi],
@@ -1486,15 +1301,15 @@ class StorageEngine:
                         "models": intent_models,
                         "new_vertices": [[d, v] for d, v in new_vertices],
                     })
-                maybe_fail("save_batch.after_intent")
+                maybe_fail(f"{label}.after_intent")
                 self.index_cache.flush()
-                maybe_fail("save_batch.after_index_flush")
+                maybe_fail(f"{label}.after_index_flush")
                 for mi in range(len(specs)):
                     self.fs.write_durable(
                         self._page_file(page_names[mi]), pages[mi],
                         site="page.write",
                     )
-                maybe_fail("save_batch.after_page_write")
+                maybe_fail(f"{label}.after_page_write")
                 for mi, (name, arch, _t) in enumerate(specs):
                     self.catalog.state.models[name] = ModelEntry(
                         model_id=model_ids[mi],
@@ -1521,18 +1336,17 @@ class StorageEngine:
                         len(pages[mi]), original_bytes[mi],
                         spaces_per_model[mi], explain_per_model[mi],
                     )
-                maybe_fail("save_batch.after_snapshot")
-                dropped_old = False
-                for mi in range(len(specs)):
-                    if olds[mi]:
-                        self._tombstone_unreferenced(old_refs[mi])
-                        self._unlink(self._page_file(olds[mi].page))
-                        self._pending_explains.pop(olds[mi].model_id, None)
-                        self._unlink(self._explain_file(olds[mi].model_id))
-                        self.page_pool.invalidate(olds[mi].page)
-                        dropped_old = True
-                if dropped_old:
+                maybe_fail(f"{label}.after_snapshot")
+                replaced = [(o, r) for o, r in zip(olds, old_refs) if o]
+                for _old, refs_old in replaced:
+                    self._tombstone_unreferenced(refs_old)
+                if replaced:
                     self.index_cache.flush()
+                for old, _refs_old in replaced:
+                    self._unlink(self._page_file(old.page))
+                    self._pending_explains.pop(old.model_id, None)
+                    self._unlink(self._explain_file(old.model_id))
+                    self.page_pool.invalidate(old.page)
                 self.catalog.commit_tx(tx)
                 self.index_cache.trim()
         finally:
@@ -1962,127 +1776,14 @@ class StorageEngine:
         cannot stall — or invalidate — this reader. ``shared_cache=False``
         bypasses the buffer pool (private page bytes and decoded payloads
         — the pre-concurrency behaviour; the concurrency benchmark uses it
-        as the serialized baseline).
+        as the serialized baseline). The capture is :meth:`load_models`'
+        of one name, under its own span (``engine.load``) and metric label.
         """
         with trace("engine.load", model=name) as op:
-            lm = self._load_model_impl(name, bits, shared_cache)
+            lm = self._load_models_impl([name], bits, shared_cache)[0]
         _M_OPS.labels("load").inc()
         _M_OP_SECONDS.labels("load").observe(op.elapsed())
         return lm
-
-    def _load_model_impl(self, name: str, bits: int | None,
-                         shared_cache: bool):
-        from .loader import LoadedModel, ModelSnapshot
-
-        self._drain_released()
-        for _attempt in range(64):
-            with trace("catalog"), self._lock:
-                entry = self.catalog.get(name)
-                if entry is None or entry.status != STATUS_COMMITTED:
-                    if entry is not None and entry.status == STATUS_CORRUPT:
-                        raise self._corrupt_error(name)
-                    raise KeyError(name)
-                page_name = entry.page
-            # Page bytes + header parse + payload slicing run outside the
-            # engine lock: page files are immutable per *name* (vacuum
-            # rewrites copy-on-write under new names), so bytes read here
-            # are consistent with whatever entry we re-validate below.
-            frame = None
-            try:
-                with trace("pool", page=page_name):
-                    if shared_cache:
-                        frame = self.page_pool.get(
-                            page_name,
-                            lambda: self._read_page_bytes(page_name),
-                        )
-                        page = self._parse_frame(frame)
-                    else:
-                        page = read_page_header(
-                            self._read_page_bytes(page_name)
-                        )
-                    dims = page_dim_keys(page)
-            except FileNotFoundError as exc:
-                # Raced a delete/replace/vacuum: re-read the entry. A frame
-                # returned by get() cannot be the raiser (its bytes loaded),
-                # but unpin defensively in case the parse path ever throws.
-                if frame is not None:
-                    self.page_pool.unpin(frame)
-                if self.read_only:
-                    # No writers exist in a degraded store: the fallback
-                    # snapshot predates this page's cleanup and the file
-                    # is permanently gone — fail typed, don't spin.
-                    self._quarantine_model(
-                        name, page_name, f"page file missing: {exc}"
-                    )
-                    raise self._corrupt_error(name) from exc
-                continue
-            except CorruptPageError as exc:
-                # Contain the damage: quarantine THIS model (the catalog
-                # keeps serving every healthy one) and fail typed. Plain
-                # I/O errors (EIO) do NOT quarantine — the disk said
-                # nothing about the bytes, only about this read.
-                if frame is not None:
-                    self.page_pool.unpin(frame)
-                self._quarantine_model(name, page_name, str(exc))
-                raise
-            except BaseException:
-                if frame is not None:
-                    self.page_pool.unpin(frame)  # corrupt page: no pin leak
-                raise
-            try:
-                with trace("snapshot"), self._lock:
-                    cur = self.catalog.get(name)
-                    if cur is not None and cur.status == STATUS_CORRUPT:
-                        raise self._corrupt_error(name)
-                    if (cur is None or cur.status != STATUS_COMMITTED
-                            or cur.page != page_name):
-                        raise _Retry
-                    for dim in dims:
-                        self._check_quarantine(dim)
-                    indexes: dict[int, HNSWIndex] = {}
-                    for dim in dims:
-                        idx = self.index_cache.get(dim)
-                        if idx is None:
-                            raise RuntimeError(
-                                f"model {name!r} references dim {dim} but no "
-                                "index exists for it (corrupt store?)"
-                            )
-                        indexes[dim] = idx
-                    epoch = self.catalog.state.epoch
-                    token = self._snap_token
-                    self._snap_token += 1
-                    self._live_snapshots[token] = epoch
-                    # The snapshot owns a COPY of the catalog row: vacuum
-                    # re-points the live entry's page at the rewritten
-                    # file, and an "immutable view" must keep naming the
-                    # page version it actually pinned.
-                    cur = dataclasses.replace(cur)
-            except _Retry:
-                if frame is not None:
-                    self.page_pool.unpin(frame)
-                continue
-            except CorruptIndexError as exc:
-                # The page is fine but a referenced index file is not:
-                # this model cannot materialize, so quarantine it (other
-                # dims' models keep serving).
-                if frame is not None:
-                    self.page_pool.unpin(frame)
-                self._quarantine_model(name, page_name, str(exc))
-                raise
-            except BaseException:
-                if frame is not None:
-                    self.page_pool.unpin(frame)
-                raise
-            snap = ModelSnapshot(
-                epoch=epoch, entry=cur, frame=frame, indexes=indexes,
-                release=_SnapshotRelease(self._released, token, frame),
-            )
-            return LoadedModel(engine=self, page=page, info=cur, bits=bits,
-                               snapshot=snap)
-        raise RuntimeError(
-            f"load_model({name!r}): catalog kept changing under the capture "
-            "loop (writer livelock?)"
-        )
 
     def load_models(self, names, bits: int | None = None) -> list:
         """Open handles over several models under ONE snapshot epoch.
@@ -2103,19 +1804,26 @@ class StorageEngine:
         if not names:
             return []
         with trace("engine.load_batch", n_models=len(names)) as op:
-            handles = self._load_models_impl(names, bits)
+            handles = self._load_models_impl(names, bits, True)
         _M_OPS.labels("load_batch").inc()
         _M_OP_SECONDS.labels("load_batch").observe(op.elapsed())
         return handles
 
-    def _load_models_impl(self, names: list, bits: int | None) -> list:
+    def _load_models_impl(self, names: list, bits: int | None,
+                          shared_cache: bool) -> list:
+        """The one load capture. Spans per model: ``catalog`` (resolve the
+        name) and ``pool`` (pin + parse the page); then one ``snapshot``
+        around the critical section that stamps every handle."""
         from .loader import LoadedModel, ModelSnapshot
         self._drain_released()
         for _attempt in range(64):
             # Phase 1 (no lock held across I/O): resolve each name to its
-            # committed page, pin + parse the frame. Same race handling as
-            # load_model — FileNotFoundError means a delete/replace/vacuum
-            # won; retry the whole batch so the view stays one-epoch.
+            # committed page, pin + parse the frame. Page files are
+            # immutable per *name* (vacuum rewrites copy-on-write under new
+            # names), so bytes read here are consistent with whatever entry
+            # phase 2 re-validates. FileNotFoundError means a
+            # delete/replace/vacuum won; retry the whole batch so the view
+            # stays one-epoch.
             # Entries are mutable lists: once a ModelSnapshot takes
             # ownership of a frame (its finalizer unpins), the slot is
             # nulled so the failure path can't double-unpin it.
@@ -2130,7 +1838,7 @@ class StorageEngine:
 
             try:
                 for name in names:
-                    with self._lock:
+                    with trace("catalog"), self._lock:
                         entry = self.catalog.get(name)
                         if entry is None or entry.status != STATUS_COMMITTED:
                             if (entry is not None
@@ -2140,22 +1848,36 @@ class StorageEngine:
                         page_name = entry.page
                     frame = None
                     try:
-                        frame = self.page_pool.get(
-                            page_name,
-                            lambda: self._read_page_bytes(page_name),
-                        )
-                        page = self._parse_frame(frame)
-                        dims = page_dim_keys(page)
+                        with trace("pool", page=page_name):
+                            if shared_cache:
+                                frame = self.page_pool.get(
+                                    page_name,
+                                    lambda: self._read_page_bytes(page_name),
+                                )
+                                page = self._parse_frame(frame)
+                            else:
+                                page = read_page_header(
+                                    self._read_page_bytes(page_name)
+                                )
+                            dims = page_dim_keys(page)
                     except FileNotFoundError as exc:
                         if frame is not None:
                             self.page_pool.unpin(frame)
                         if self.read_only:
+                            # No writers exist in a degraded store: the
+                            # fallback snapshot predates this page's cleanup
+                            # and the file is permanently gone — fail
+                            # typed, don't spin.
                             self._quarantine_model(
                                 name, page_name, f"page file missing: {exc}"
                             )
                             raise self._corrupt_error(name) from exc
                         raise _Retry from exc
                     except CorruptPageError as exc:
+                        # Contain the damage: quarantine THIS model (the
+                        # catalog keeps serving every healthy one) and fail
+                        # typed. Plain I/O errors (EIO) do NOT quarantine —
+                        # the disk said nothing about the bytes.
                         if frame is not None:
                             self.page_pool.unpin(frame)
                         self._quarantine_model(name, page_name, str(exc))
@@ -2169,7 +1891,7 @@ class StorageEngine:
                 # Phase 2: ONE critical section — re-validate every entry
                 # against the page version actually pinned, then stamp all
                 # snapshots with the same epoch.
-                with self._lock:
+                with trace("snapshot"), self._lock:
                     entries = []
                     for name, page_name, _frame, _page, dims in prepared:
                         cur = self.catalog.get(name)
@@ -2180,6 +1902,10 @@ class StorageEngine:
                             raise _Retry
                         for dim in dims:
                             self._check_quarantine(dim)
+                        # The snapshot owns a COPY of the catalog row:
+                        # vacuum re-points the live entry's page at the
+                        # rewritten file, and an "immutable view" must keep
+                        # naming the page version it actually pinned.
                         entries.append(dataclasses.replace(cur))
                     index_sets = []
                     for rec in prepared:
@@ -2215,9 +1941,9 @@ class StorageEngine:
                 _unpin_prepared()
                 continue
             except CorruptIndexError as exc:
-                # Index damage discovered during capture: quarantine the
-                # model whose dims were being resolved; fail the batch typed
-                # (other models stay healthy).
+                # The pages are fine but a referenced index file is not:
+                # quarantine the model whose dims were being resolved (other
+                # dims' models keep serving) and fail typed.
                 _unpin_prepared()
                 for name, page_name in corrupt_at:
                     self._quarantine_model(name, page_name, str(exc))
@@ -2231,8 +1957,8 @@ class StorageEngine:
                 for rec, snap in zip(prepared, snaps)
             ]
         raise RuntimeError(
-            f"load_models({names!r}): catalog kept changing under the batch "
-            "capture loop (writer livelock?)"
+            f"load of {names!r}: catalog kept changing under the capture "
+            "loop (writer livelock?)"
         )
 
     # ------------------------------------------------------------- integrity

@@ -22,6 +22,7 @@ from repro.core.quantize import (
     quantize_linear,
     quantize_linear_batch,
 )
+from repro.obs.trace import recent_traces
 
 RNG = np.random.default_rng(33)
 TOL = 2.0 ** -24 * 1.001 + 1e-9  # default tolerance + fp slack
@@ -432,6 +433,89 @@ def test_save_models_rejects_duplicate_names(tmp_path):
     with pytest.raises(ValueError):
         eng.save_models([("m", {}, t), ("m", {}, t)])
     assert eng.save_models([]) == []
+
+
+def _one_save_inputs(kind):
+    """A base model plus the next version the test saves (``kind``):
+    a near fine-tune (delta outcomes) or far-away tensors (new bases).
+    Shapes share dims so intra-save dedup and one-dim groups both occur,
+    and a scalar and a constant tensor take the nbit=0 / scale=0 paths."""
+    rng = np.random.default_rng(21)
+    base = {
+        "emb": rng.normal(0, 0.02, (40, 24)).astype(np.float32),
+        "w0": rng.normal(0, 0.02, (24, 24)).astype(np.float32),
+        "w1": rng.normal(0, 0.02, (24, 24)).astype(np.float32),
+        "b": rng.normal(0, 0.02, 24).astype(np.float32),
+        "s": np.float32(0.5).reshape(()),
+        "c": np.full(24, 0.25, np.float32),
+    }
+    if kind == "fine_tune":
+        nxt = {k: (v + rng.normal(0, 1e-5, v.shape)).astype(np.float32)
+               for k, v in base.items()}
+    else:
+        nxt = {k: rng.normal(0, 5.0, v.shape).astype(np.float32)
+               for k, v in base.items()}
+        nxt["w1"] = (nxt["w0"] + 1e-5).astype(np.float32)
+    return base, nxt
+
+
+def _span_tree(span):
+    return [span.name, [_span_tree(c) for c in span.children]]
+
+
+@pytest.mark.parametrize("kind", ["fine_tune", "distinct"])
+@pytest.mark.parametrize("step", ["save", "replace"])
+def test_save_model_is_save_models_of_one(tmp_path, step, kind):
+    """``save_model`` and ``save_models`` of one model run one
+    transaction: for a save and for a replace they leave byte-identical
+    page files, equal reports (but for wall time), equal EXPLAIN rows and
+    refs, and the same span tree under their own root."""
+    base, nxt = _one_save_inputs(kind)
+    name = "m" if step == "save" else "base"
+    runs = {}
+    for route, root_span in (("save_model", "engine.save"),
+                             ("save_models", "engine.save_batch")):
+        root = tmp_path / route
+        eng = StorageEngine(str(root))
+        eng.save_model("base", {"v": 1}, base)
+        if route == "save_model":
+            report = eng.save_model(name, {"v": 2}, nxt)
+        else:
+            (report,) = eng.save_models([(name, {"v": 2}, nxt)])
+        span = [s for s in recent_traces() if s.name == root_span][-1]
+        pages_dir = root / "pages"
+        runs[route] = {
+            "pages": {f: (pages_dir / f).read_bytes()
+                      for f in sorted(os.listdir(pages_dir))},
+            "report": {k: v for k, v in report.to_dict().items()
+                       if k != "seconds"},
+            "explain": eng.model_explain(name)["explain"],
+            "refs": dict(eng.catalog.state.vertex_refs),
+            "models": sorted(eng.list_models()),
+            "tree": _span_tree(span)[1],
+        }
+        eng.close()
+    one, batch = runs["save_model"], runs["save_models"]
+    assert one["pages"] == batch["pages"]
+    assert len(one["pages"]) == (2 if step == "save" else 1)
+    assert one["report"] == batch["report"]
+    assert one["explain"] == batch["explain"] == one["report"]["explain"]
+    outcomes = {ex["outcome"] for ex in one["explain"]}
+    if kind == "fine_tune":
+        assert outcomes == {"delta"}
+    else:  # a scalar is always a delta (its range is 0)
+        assert outcomes == {"new_base", "intra_save_dedup", "delta"}
+    assert one["refs"] == batch["refs"]
+    assert one["models"] == batch["models"]
+    assert one["tree"] == batch["tree"]
+    # engine.save > probe (> quantized_l2, delta), quantize (> encode × n),
+    # pack, commit (> journal) — the tree the ingest metrics read.
+    tree = dict(one["tree"])
+    assert [n for n, _ in one["tree"]] == ["probe", "quantize", "pack", "commit"]
+    assert {n for n, _ in tree["probe"]} == {"quantized_l2", "delta"}
+    assert [n for n, _ in tree["quantize"]] == ["encode"] * len(nxt)
+    assert tree["pack"] == []
+    assert [n for n, _ in tree["commit"]] == ["journal"]
 
 
 def _assert_consistent(eng):

@@ -137,8 +137,10 @@ def test_concurrent_readers_and_writer_thread_stress(tmp_path):
         while not stop.is_set():
             name = f"m{k % 2}"
             new = _model()
-            eng.replace_model(name, {}, new)
+            # Commit and record under one lock: a reader that sees the new
+            # version compares it only once it is recorded.
             with version_lock:
+                eng.replace_model(name, {}, new)
                 versions[name].append(eng.load_model(name).materialize())
             eng.vacuum()
             k += 1

@@ -18,6 +18,7 @@ from repro.core import catalog as catmod
 from repro.core.catalog import STATUS_COMMITTED, InjectedCrash, ModelEntry
 from repro.core.hnsw import HNSWIndex
 from repro.core.hnsw_ref import quantized_l2_batch_dense
+from repro.core.integrity import journal_line, parse_journal_record
 from repro.core.pages import read_page_header, read_record, remap_page_vertices
 
 RNG = np.random.default_rng(11)
@@ -411,6 +412,77 @@ def test_crash_during_save_replays_consistent(tmp_path, point):
     else:
         assert eng2.list_models() == ["keep"]
     assert_consistent(eng2)
+    out = eng2.load_model("keep").materialize()
+    assert all(np.array_equal(out[k], keep[k]) for k in keep)
+
+
+def _rewrite_as_single_model_intent(root: str, op: str) -> dict:
+    """Rewrite a crashed save's pending ``save_batch`` intent into the
+    one-model ``save``/``replace`` shape earlier versions journaled."""
+    path = os.path.join(root, "journal.jsonl")
+    with open(path) as f:
+        (rec,) = [parse_journal_record(line) for line in f if line.strip()]
+    assert rec["op"] == "save_batch"
+    (model,) = rec["models"]
+    assert ("old_page" in model) == (op == "replace")
+    old = {"tx": rec["tx"], "op": op,
+           "new_vertices": rec["new_vertices"], **model}
+    with open(path, "w") as f:
+        f.write(journal_line(old))
+    return old
+
+
+@pytest.mark.parametrize("switched", [False, True])
+@pytest.mark.parametrize("op", ["save", "replace"])
+def test_replay_of_single_model_intent(tmp_path, op, switched):
+    """A store holding a pending ``save``/``replace`` intent of an earlier
+    version replays it like the one save path: committed when the snapshot
+    switched (a replace then drops the old page and tombstones its zero-ref
+    vertices), else rolled back with no orphan page and no dangling ref."""
+    eng = StorageEngine(str(tmp_path))
+    eng.save_model("keep", {}, _tensors())
+    keep = eng.load_model("keep").materialize()
+    name = "doomed" if op == "save" else "m"
+    if op == "replace":
+        eng.save_model("m", {}, _distinct())
+    before = (eng.load_model(name).materialize()
+              if op == "replace" else None)
+    catmod.FAILPOINTS.add(
+        "save.after_snapshot" if switched else "save.after_page_write")
+    with pytest.raises(InjectedCrash):
+        eng.save_model(name, {}, _distinct())
+    catmod.FAILPOINTS.clear()
+    rec = _rewrite_as_single_model_intent(str(tmp_path), op)
+    assert rec["new_vertices"]
+    assert os.path.exists(os.path.join(str(tmp_path), "pages", rec["page"]))
+
+    eng2 = StorageEngine(str(tmp_path))
+    assert eng2.catalog.pending() == []
+    assert_consistent(eng2)
+    entry = eng2.catalog.get(name)
+    new_vertices = [(d, v) for d, v in rec["new_vertices"]]
+    if switched:
+        assert entry is not None and entry.model_id == rec["id"]
+        live = new_vertices
+        dropped = [(d, v) for d, v, _c in rec.get("old_refs", [])]
+        if op == "replace":
+            assert dropped
+            assert not os.path.exists(
+                os.path.join(str(tmp_path), "pages", rec["old_page"]))
+    else:
+        if op == "save":
+            assert entry is None
+        else:
+            assert entry.model_id == rec["old_id"]
+            out = eng2.load_model(name).materialize()
+            assert all(np.array_equal(out[k], before[k]) for k in before)
+        live, dropped = [], new_vertices
+    for dim, vid in live:
+        assert eng2.catalog.ref_count(dim, vid) > 0
+        assert not eng2.index_cache.get(dim).is_deleted(vid)
+    for dim, vid in dropped:
+        assert eng2.catalog.ref_count(dim, vid) == 0
+        assert eng2.index_cache.get(dim).is_deleted(vid)
     out = eng2.load_model("keep").materialize()
     assert all(np.array_equal(out[k], keep[k]) for k in keep)
 

@@ -22,6 +22,7 @@ The registry is process-global, so every assertion is on *deltas*
 around the workload, never absolutes.
 """
 
+import gc
 import importlib.util
 import json
 import logging
@@ -172,6 +173,10 @@ def test_counters_move_and_are_monotonic(tmp_path):
 
 
 def test_gauges_track_engine_state(tmp_path):
+    # The gauge sums every engine still alive in this process. Collect the
+    # unreachable engines earlier tests left first, so none drops out of
+    # the sum between the readings below.
+    gc.collect()
     base_models = _value("neurstore_engine_models")
     eng = StorageEngine(str(tmp_path))
     eng.save_model("a", {"f": 1}, _tensors(seed=3))
